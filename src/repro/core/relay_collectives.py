@@ -61,13 +61,12 @@ def relay_broadcast(x: jax.Array, mesh: Mesh, axis: str = "pod",
     ``axis`` slices hold the ``src`` slice's value."""
     other = tuple(a for a in mesh.axis_names if a != axis)
     spec_in = P()   # replicated input per-slice (value differs across axis)
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(relay_broadcast_inner, axis_name=axis,
                           axis_size=mesh.shape[axis], src=src,
                           n_chunks=n_chunks),
         mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
-        check=False)
+        check_vma=False)
     # reshape: treat axis as a leading stacked dim
     stacked = x  # (P * chunk, ...) layout: caller passes axis-stacked array
     return fn(stacked)

@@ -7,17 +7,22 @@ The lanes engine's hot inner operation is ``advance_segment`` over
 * ``numpy`` — the bit-exact reference (``repro.core.transport``'s own
   module function; the scalar engine runs the same expressions).
 * ``jax``   — ``jax.jit(jax.vmap(...))`` of an elementwise per-lane step,
-  run under a scoped x64 context (``jax.experimental.enable_x64`` — the
+  run under a scoped x64 context (``with jax.enable_x64(True)`` — the
   global flag is never touched, so f32 model code elsewhere is unaffected).
-* ``pallas`` — the ``repro.kernels.lane_step`` kernel (interpret mode on
-  CPU; set ``interpret=False`` on a real TPU).
+  This is the device path: on a TPU, XLA emulates the float64.
+* ``pallas`` — the ``repro.kernels.lane_step`` kernel, interpreted on the
+  CPU backend only; asking for it on an accelerator raises, because
+  compiled Pallas has no float64.
 
 The jax/Pallas backends agree with numpy to float64 round-off but NOT
 necessarily bit-for-bit: XLA may contract ``bytes_done + rate * t`` into an
-FMA.  The determinism contract therefore names numpy the reference backend
+FMA.  On a TPU the jax backend's float64 is emulated, and the quantities
+that pass through the division (``adv``, ``moved``, ``t_left``) land about
+1e-10 relative from numpy on a v5e, so trajectories drift further there.
+The determinism contract therefore names numpy the reference backend
 — the lane-0 bit-identity gate always runs it — while the accelerated
 backends are validated by ``tests/test_ensemble.py`` elementwise against
-the reference.
+the reference, and on the chip by ``chip_smoke.py`` lane for lane.
 
 ``BatchedFaultInjector`` wraps N independent per-lane ``FaultInjector``
 streams behind one dense-array call.  This is deliberately NOT a vmapped
@@ -59,17 +64,24 @@ def _lane_segment_jnp(t, bytes_done, rate, bound):
 _JAX_FN = None
 
 
+def jax_segment_device(t, bytes_done, rate, bound):
+    """The jit(vmap) step on the default device; returns device arrays.
+    Call it inside ``jax.enable_x64(True)``."""
+    global _JAX_FN
+    import jax
+    if _JAX_FN is None:
+        _JAX_FN = jax.jit(jax.vmap(_lane_segment_jnp))
+    t = np.broadcast_to(np.asarray(t, np.float64), np.shape(bytes_done))
+    return _JAX_FN(jnp_f64(t), jnp_f64(bytes_done), jnp_f64(rate),
+                   jnp_f64(bound))
+
+
 def jax_segment_fn(t, bytes_done, rate, bound):
     """jit(vmap) backend.  Inputs/outputs are host numpy float64; x64 is
     enabled only inside this call."""
-    global _JAX_FN
     import jax
-    with jax.experimental.enable_x64():
-        if _JAX_FN is None:
-            _JAX_FN = jax.jit(jax.vmap(_lane_segment_jnp))
-        t = np.broadcast_to(np.asarray(t, np.float64), bytes_done.shape)
-        out = _JAX_FN(jnp_f64(t), jnp_f64(bytes_done), jnp_f64(rate),
-                      jnp_f64(bound))
+    with jax.enable_x64(True):
+        out = jax_segment_device(t, bytes_done, rate, bound)
         t_left, new_bytes, adv, moved, hit = (np.asarray(o) for o in out)
     return t_left, new_bytes, adv, moved, hit
 
@@ -80,7 +92,8 @@ def jnp_f64(x):
 
 
 def pallas_segment_fn(t, bytes_done, rate, bound):
-    """Pallas kernel backend (interpret mode; see repro.kernels.lane_step)."""
+    """Pallas kernel backend (CPU interpret mode only; see
+    repro.kernels.lane_step)."""
     from repro.kernels.lane_step.ops import lane_segment_step
     t = np.broadcast_to(np.asarray(t, np.float64), bytes_done.shape)
     return lane_segment_step(t, bytes_done, rate, bound)
@@ -92,6 +105,8 @@ def make_segment_fn(backend: str):
     if backend == "jax":
         return jax_segment_fn
     if backend == "pallas":
+        from repro.kernels.lane_step.ops import require_cpu_backend
+        require_cpu_backend()
         return pallas_segment_fn
     raise ValueError(f"unknown segment backend {backend!r}")
 

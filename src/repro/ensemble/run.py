@@ -29,6 +29,7 @@ import sys
 import time
 from typing import Optional, Sequence
 
+from repro.compile_cache import enable_compile_cache
 from repro.ensemble.engine import run_ensemble, scalar_lane
 from repro.ensemble.search import SearchDriver
 from repro.ensemble.spec import EnsembleSpec
@@ -105,6 +106,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         p.error("--ensemble NAME required (or --list)")
 
     espec = _get_ensemble(args.ensemble, args.lanes)
+    if args.backend != "numpy":
+        enable_compile_cache()
     t0 = time.perf_counter()
 
     if args.check_lane0:

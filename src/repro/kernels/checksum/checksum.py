@@ -13,11 +13,13 @@ streaming over HBM with a tiny VMEM-resident state.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
 from repro.kernels.checksum.ref import PHI, ROW
 
 BLOCK_ROWS = 256          # rows of 512 words per grid step (512 KB per block)
@@ -50,17 +52,21 @@ def _checksum_kernel(nw_ref, x_ref, acc_ref):
     # zero-padding beyond the true word count must not contribute
     nw = nw_ref[0, 0]
     g = jnp.where(idx < nw, g, jnp.uint32(0))
-    # fold BLOCK_ROWS -> ACC_ROWS so the accumulator stays tiny
-    g = g.reshape(ACC_ROWS, r // ACC_ROWS, c)
-    part = jax.lax.reduce(g, jnp.uint32(0), jax.lax.bitwise_xor, (1,))
+    # fold BLOCK_ROWS -> ACC_ROWS with XORs of whole (8, 512) tiles, so the
+    # accumulator stays tiny; the TPU lowering has no in-kernel reduce
+    part = g[:ACC_ROWS]
+    for k in range(ACC_ROWS, r, ACC_ROWS):
+        part = part ^ g[k:k + ACC_ROWS]
     acc_ref[...] ^= part
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def checksum_words_pallas(words: jax.Array, n_words: jax.Array,
-                          nbytes: jax.Array, interpret: bool = True) -> jax.Array:
+                          nbytes: jax.Array,
+                          interpret: Optional[bool] = None) -> jax.Array:
     """words: uint32[N] with N % (BLOCK_ROWS*ROW) == 0 (pre-padded by ops.py);
     n_words: true (unpadded) word count; nbytes: true byte length.
+    ``interpret=None`` interprets on the CPU backend only.
 
     Returns the uint32 scalar hash (bit-identical to the numpy reference).
     """
@@ -76,7 +82,7 @@ def checksum_words_pallas(words: jax.Array, n_words: jax.Array,
                   pl.BlockSpec((BLOCK_ROWS, ROW), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((ACC_ROWS, ROW), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((ACC_ROWS, ROW), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(nw, x2)
     h = jax.lax.reduce(acc.reshape(-1), jnp.uint32(0),
                        jax.lax.bitwise_xor, (0,))

@@ -6,6 +6,8 @@ true byte length is folded into the finalizer — identical to the reference.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import numpy as np
 
 import jax
@@ -25,7 +27,8 @@ def _pad_words(words: jnp.ndarray) -> jnp.ndarray:
     return words
 
 
-def checksum_array(x: jax.Array, interpret: bool = True) -> jax.Array:
+def checksum_array(x: jax.Array,
+                   interpret: Optional[bool] = None) -> jax.Array:
     """Hash a jax array's raw contents (uint32 view, zero-padded)."""
     raw = jnp.asarray(x).reshape(-1)
     if raw.dtype != jnp.uint32:
@@ -42,7 +45,7 @@ def checksum_array(x: jax.Array, interpret: bool = True) -> jax.Array:
                                  interpret=interpret)
 
 
-def checksum_bytes(data: bytes, interpret: bool = True) -> int:
+def checksum_bytes(data: bytes, interpret: Optional[bool] = None) -> int:
     words = jnp.asarray(bytes_to_words(data))
     n_words = words.size
     words = _pad_words(words)

@@ -19,8 +19,6 @@ from __future__ import annotations
 
 import numpy as np
 
-import jax.numpy as jnp
-
 PHI = np.uint32(0x9E3779B1)
 LANES = 128
 ROW = 512          # words per kernel row (4 sublanes x 128 lanes)
@@ -44,6 +42,7 @@ def finalize32_np(h: int, nbytes: int) -> int:
 
 
 def _mix32_jnp(x):
+    import jax.numpy as jnp
     x = x.astype(jnp.uint32)
     x = x ^ (x >> jnp.uint32(16))
     x = x * jnp.uint32(0x7FEB352D)
@@ -85,9 +84,12 @@ def checksum_bytes_np(data: bytes) -> int:
     return checksum_words_np(bytes_to_words(data), len(data))
 
 
-def checksum_words_jnp(words: jnp.ndarray, nbytes: int) -> jnp.ndarray:
-    """Pure-jnp oracle; words: uint32[N] (already padded)."""
+def checksum_words_jnp(words, nbytes: int):
+    """Pure-jnp oracle; words: uint32[N] (already padded).  jax is imported
+    here, not at module level: host-only simulator workers import this
+    module for the numpy hashers and must not load jax."""
     import jax
+    import jax.numpy as jnp
     idx = jnp.arange(words.size, dtype=jnp.uint32)
     g = _mix32_jnp(words.astype(jnp.uint32) ^ (idx * jnp.uint32(PHI)))
     h = jax.lax.reduce(g, jnp.uint32(0), jax.lax.bitwise_xor, (0,))

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 BQ = 128
 BK = 128
 NEG_INF = -1e30
@@ -76,8 +78,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            window: Optional[int] = None,
-                           interpret: bool = True) -> jax.Array:
-    """q, k, v: (B, H, T, d), T % 128 == 0.  Causal; optional sliding window."""
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """q, k, v: (B, H, T, d), T % 128 == 0.  Causal; optional sliding window.
+    ``interpret=None`` interprets on the CPU backend only."""
     B, H, T, d = q.shape
     bq, bk = min(BQ, T), min(BK, T)
     assert T % bq == 0 and T % bk == 0, (T, bq, bk)
@@ -103,6 +106,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(B, H, T, d)

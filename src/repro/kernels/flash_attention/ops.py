@@ -1,8 +1,8 @@
 """Public op: GQA-aware flash attention wrapper.
 
 Maps (B, T, H, hd) GQA layouts onto the (B, H, T, hd) kernel, repeating KV
-heads per group.  ``use_pallas=False`` routes to the jnp oracle (the path the
-dry-run lowers, so cost analysis sees real HLO; see DESIGN.md §8).
+heads per group.  ``use_pallas=False`` routes to the jnp oracle.  No model calls this op;
+the models run their own chunked attention in ``models/layers.py``.
 """
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     window: Optional[int] = None,
                     use_pallas: bool = True,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, T, H, hd); k, v: (B, T, Hkv, hd) with H % Hkv == 0 -> (B,T,H,hd)."""
     B, T, H, hd = q.shape
     Hkv = k.shape[2]

@@ -12,19 +12,24 @@ walks 8-lane blocks.  Padding rows carry ``rate = 0`` and ``bound =
 bytes_done``, which the engine masks out anyway (``hit`` on a PAD row is
 never read).
 
-Runs in interpret mode by default so CPU CI exercises the identical program;
-on a real TPU pass ``interpret=False`` (float64 stays supported on TPU only
-via interpret mode — compiled mode would need an f32 split-hi/lo scheme, a
-deliberate non-goal while the trajectory contract is float64)."""
+The trajectory contract is float64, and compiled Pallas on a TPU has no
+float64: the TPU compiler refuses this kernel in f64.  So it runs only in
+interpret mode on the CPU backend, and ``ops.lane_segment_step`` refuses
+any other backend before compiling; the ensemble's ``jax`` backend is the
+device path (XLA rewrites its f64).  Running compiled on a chip would need
+an f32 hi/lo formulation of the step."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE_BLOCK = 8          # sublane tile for f32/f64 interpret mode
+from repro.kernels import resolve_interpret
+
+LANE_BLOCK = 8          # sublane tile
 ROW_TILE = 128          # last-dim tile
 
 
@@ -50,19 +55,20 @@ def _lane_step_kernel(t_ref, bd_ref, rate_ref, bound_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def lane_step_pallas(t: jax.Array, bytes_done: jax.Array, rate: jax.Array,
-                     bound: jax.Array, interpret: bool = True):
-    """All inputs float64 [L, R] with L % 8 == 0 and R % 128 == 0 (pre-padded
-    by ops.py).  Returns (t_left, new_bytes, adv, moved, hit[bool])."""
+                     bound: jax.Array, interpret: Optional[bool] = None):
+    """All inputs one float dtype, [L, R] with L % 8 == 0 and R % 128 == 0
+    (pre-padded by ops.py).  Returns (t_left, new_bytes, adv, moved,
+    hit[bool]); ``interpret=None`` interprets on the CPU backend only."""
     L, R = bytes_done.shape
     grid = (L // LANE_BLOCK,)
     spec = pl.BlockSpec((LANE_BLOCK, R), lambda i: (i, 0))
-    f64 = jax.ShapeDtypeStruct((L, R), jnp.float64)
+    fl = jax.ShapeDtypeStruct((L, R), bytes_done.dtype)
     return pl.pallas_call(
         _lane_step_kernel,
         grid=grid,
         in_specs=[spec, spec, spec, spec],
         out_specs=[spec, spec, spec, spec, spec],
-        out_shape=[f64, f64, f64, f64,
+        out_shape=[fl, fl, fl, fl,
                    jax.ShapeDtypeStruct((L, R), jnp.bool_)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(t, bytes_done, rate, bound)

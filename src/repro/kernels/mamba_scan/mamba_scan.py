@@ -16,12 +16,14 @@ u/dt/B/C blocks (~4 × CHUNK_T × BLOCK_D × 4B) fit comfortably in VMEM.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 CHUNK_T = 128
 BLOCK_D = 256
@@ -56,9 +58,10 @@ def _scan_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, h0_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def selective_scan_pallas(u: jax.Array, dt: jax.Array, Bm: jax.Array,
                           Cm: jax.Array, A: jax.Array, h0: jax.Array,
-                          interpret: bool = True
+                          interpret: Optional[bool] = None
                           ) -> Tuple[jax.Array, jax.Array]:
-    """Same contract as ``ref.selective_scan_ref`` (all f32).
+    """Same contract as ``ref.selective_scan_ref`` (all f32);
+    ``interpret=None`` interprets on the CPU backend only.
 
     Requires T % CHUNK_T == 0 and D % BLOCK_D == 0 when larger than the block
     (callers pad; the assigned arch shapes satisfy this natively:
@@ -91,6 +94,6 @@ def selective_scan_pallas(u: jax.Array, dt: jax.Array, Bm: jax.Array,
             jax.ShapeDtypeStruct((B, D, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, dt, Bm, Cm, A, h0)
     return y, hT

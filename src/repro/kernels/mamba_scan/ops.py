@@ -1,7 +1,7 @@
 """Public op: selective scan with automatic padding to kernel granularity."""
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +12,8 @@ from repro.kernels.mamba_scan.ref import selective_scan_ref
 
 
 def selective_scan(u, dt, Bm, Cm, A, h0, use_pallas: bool = True,
-                   interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                   interpret: Optional[bool] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
     """u, dt: (B,T,D); Bm, Cm: (B,T,N); A: (D,N); h0: (B,D,N)."""
     if not use_pallas:
         return selective_scan_ref(u, dt, Bm, Cm, A, h0)

@@ -14,6 +14,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.models.model import LM
 from repro.serve.engine import Engine
@@ -31,6 +32,7 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     import jax
 

@@ -17,6 +17,7 @@ import os
 import sys
 
 from repro.checkpoint.replicate import CheckpointReplicator
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.train.loop import TrainConfig, train
 
@@ -40,6 +41,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--remat", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -185,14 +185,13 @@ def _moe_forward_shardmap(p: Params, cfg: ModelConfig, x: jnp.ndarray,
             aux = jax.lax.pmean(aux, dp_axes)
         return out, aux
 
-    from repro.compat import shard_map as _shard_map
-    fn = _shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(dp_spec, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(dp_spec, None), P()),
-        check=False)
+        check_vma=False)
     out, aux = fn(x.reshape(N, d), p["router"], p["w_gate"], p["w_up"],
                   p["w_down"])
     return out.astype(x.dtype).reshape(B, T, d), aux

@@ -54,6 +54,9 @@ class TrainResult:
     restarts: int
     restored_from: Optional[str] = None
     wall_s: float = 0.0
+    # final {"params", "opt"} on the device: the state the last checkpoint
+    # (if ``steps`` is a multiple of ``ckpt_every``) saved
+    state: Optional[Dict[str, Any]] = None
 
 
 def make_train_step(model: LM, opt_cfg: adamw.AdamWConfig,
@@ -150,7 +153,8 @@ def train(cfg: ModelConfig, tc: TrainConfig,
                             d, tc.replicator.site_dir(tc.replicator.primary))
                         tc.replicator.replicate(rel)
             return TrainResult(losses, tc.steps, restarts, restored_from,
-                               time.time() - t0)
+                               time.time() - t0,
+                               state={"params": params, "opt": opt_state})
         except SimulatedFailure as e:
             print(f"[train] FAILURE: {e}; restarting from checkpoint")
             restarts += 1

@@ -5,7 +5,18 @@ import sys
 # Multi-device / x64 tests spawn subprocesses whose environment comes from
 # jax_subprocess_env below, the one place that composes jax env policy.
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+sys.path.insert(0, os.path.join(ROOT, "src"))
+
+
+def load_chip_smoke():
+    """The repo-root ``chip_smoke.py`` as a module (it is not a package)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(ROOT, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def jax_subprocess_env(devices=None, x64=False):

@@ -40,30 +40,32 @@ _SUBPROC = textwrap.dedent("""
     x = jnp.arange(8 * 16 * 4, dtype=jnp.float32).reshape(8 * 16, 4)
     # stacked along pod: slice p holds rows [16p, 16p+16); src slice = 0
 
-    from repro.compat import shard_map
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         functools.partial(relay_broadcast_inner, axis_name="pod",
                           axis_size=8, src=0, n_chunks=4),
-        mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod")))
+        mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod"),
+        check_vma=False))
     out = np.asarray(fn(x)).reshape(8, 16, 4)
     src_block = np.asarray(x[:16])
     for p in range(8):
         np.testing.assert_array_equal(out[p], src_block)
     print("RELAY_OK")
 
-    fn2 = jax.jit(shard_map(
+    fn2 = jax.jit(jax.shard_map(
         functools.partial(naive_broadcast_inner, axis_name="pod",
                           axis_size=8, src=0),
-        mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod")))
+        mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod"),
+        check_vma=False))
     out2 = np.asarray(fn2(x)).reshape(8, 16, 4)
     for p in range(8):
         np.testing.assert_array_equal(out2[p], src_block)
     print("NAIVE_OK")
 
     y = jnp.arange(8 * 4.0, dtype=jnp.float32).reshape(8, 4)
-    fn3 = jax.jit(shard_map(
+    fn3 = jax.jit(jax.shard_map(
         functools.partial(ring_all_gather_inner, axis_name="pod", axis_size=8),
-        mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod")))
+        mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod"),
+        check_vma=False))
     out3 = np.asarray(fn3(y)).reshape(8, 8, 4)
     for p in range(8):
         np.testing.assert_array_equal(out3[p], np.asarray(y))
@@ -96,10 +98,10 @@ def test_compressed_psum_on_4_devices():
         mesh = jax.make_mesh((4,), ("pod",))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(4, 32)), jnp.float32)
-        from repro.compat import shard_map
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             functools.partial(psum_compressed, axis_name="pod"),
-            mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod")))
+            mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod"),
+            check_vma=False))
         out = np.asarray(fn(g)).reshape(4, 32)
         want = np.mean(np.asarray(g).reshape(4, 32), axis=0)
         for p in range(4):

@@ -124,6 +124,19 @@ def test_lanes_engine_runs_on_accelerated_backends(backend):
         assert not alt.lane(i).timed_out
 
 
+def test_pallas_backend_refuses_accelerators(monkeypatch):
+    """Compiled Pallas has no float64 on a TPU: asking for the lane-step
+    kernel off the CPU backend fails loudly before anything compiles."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="float64"):
+        make_segment_fn("pallas")
+    espec = EnsembleSpec("t-pallas-tpu", get_scenario("paper-2022"),
+                         n_lanes=1)
+    with pytest.raises(RuntimeError, match="float64"):
+        run_ensemble(espec, scale=SCALE, n_datasets=ND, backend="pallas")
+
+
 # ------------------------------------------------------------------- search
 def test_search_checkpoint_resume(tmp_path):
     ckpt = str(tmp_path / "search.json")
@@ -218,6 +231,8 @@ def test_quantile_bands_permutation_invariant_hypothesis():
                 for i, v in enumerate(vals)]
         perm = list(rows)
         np.random.default_rng(seed).shuffle(perm)
-        assert quantile_bands(rows) == quantile_bands(perm)
+        metrics = ("sim_days", "faults_total")
+        assert (quantile_bands(rows, metrics=metrics)
+                == quantile_bands(perm, metrics=metrics))
 
     prop()

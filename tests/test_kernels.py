@@ -1,5 +1,6 @@
-"""Per-kernel validation: shape/dtype sweeps against the pure-jnp oracles
-(kernels run in interpret mode on CPU; see DESIGN.md §8)."""
+"""Per-kernel validation: shape/dtype sweeps against the pure-jnp oracles.
+On the CPU backend every wrapper runs its kernel in interpret mode; the
+compiled TPU lowering is checked in tests/test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,6 +27,16 @@ def test_checksum_matches_refs(size):
     jref = int(checksum_words_jnp(jnp.asarray(bytes_to_words(data)), size))
     pal = checksum_bytes(data)
     assert ref == jref == pal
+
+
+def test_interpret_mode_follows_the_backend(monkeypatch):
+    """Kernels interpret on the CPU backend only; an explicit choice wins."""
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True
 
 
 def test_checksum_order_sensitive():

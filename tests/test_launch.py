@@ -110,3 +110,28 @@ def test_serve_cli_smoke():
     rc = main(["--arch", "smollm-135m", "--requests", "2", "--max-new", "3",
                "--max-batch", "2", "--max-seq", "64"])
     assert rc == 0
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    """The persistent cache lives in $JAX_COMPILATION_CACHE_DIR when set
+    (left to JAX, nothing else set), else at the fixed <repo>/.jax_cache."""
+    import os
+
+    from repro.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if env_dir:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / env_dir))
+            assert enable_compile_cache() == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir is None
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            got = enable_compile_cache()
+            assert got == str(REPO_CACHE_DIR) == jax.config.jax_compilation_cache_dir
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
