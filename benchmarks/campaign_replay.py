@@ -586,11 +586,6 @@ def ensemble_bench(n_lanes: int = 256, scale: float = 0.002,
     return doc
 
 
-# promoted to src/repro/obs/profile.py; the bench keeps this alias so any
-# external caller of benchmarks.campaign_replay._PhaseProfiler still works
-_PhaseProfiler = PhaseProfiler
-
-
 def profile_run(scenario: str = "paper-2022", n_datasets: int = None,
                 seed: int = 0, scale: float = 1.0) -> dict:
     """One instrumented event-engine replay split into per-phase buckets:

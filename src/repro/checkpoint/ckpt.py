@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from repro.core.integrity import Manifest
+from repro.obs import spans
 
 PyTree = Any
 _LEAF_RE = re.compile(r"leaf-(\d{5})\.c(\d{2})\.npy$")
@@ -42,48 +43,60 @@ def save_checkpoint(ckpt_root: str, step: int, tree: PyTree,
                     data_state_path: Optional[str] = None,
                     n_chunks: int = 4, keep: int = 3) -> str:
     """Write checkpoint for ``step``; returns the committed directory."""
-    final = os.path.join(ckpt_root, f"step-{step:06d}")
-    tmp = final + ".tmp"
-    if os.path.exists(tmp):
-        shutil.rmtree(tmp)
-    os.makedirs(tmp, exist_ok=True)
+    with spans.span("ckpt.save", step=step) as save:
+        final = os.path.join(ckpt_root, f"step-{step:06d}")
+        tmp = final + ".tmp"
+        if os.path.exists(tmp):
+            shutil.rmtree(tmp)
+        os.makedirs(tmp, exist_ok=True)
 
-    leaves, treedef = _flatten(tree)
-    meta: List[Dict] = []
-    for i, leaf in enumerate(leaves):
-        arr = np.asarray(jax.device_get(leaf))
-        # bf16 has no numpy dtype; persist as uint16 view + dtype tag
-        dtype_tag = str(leaf.dtype) if hasattr(leaf, "dtype") else str(arr.dtype)
-        if dtype_tag == "bfloat16":
-            arr = arr.view(np.uint16)
-        chunks = max(1, min(n_chunks, arr.shape[0] if arr.ndim else 1))
-        bounds = np.linspace(0, arr.shape[0] if arr.ndim else 1,
-                             chunks + 1).astype(int) if arr.ndim else [0, 1]
-        files = []
-        for c in range(chunks):
-            name = f"leaf-{i:05d}.c{c:02d}.npy"
-            if arr.ndim:
-                np.save(os.path.join(tmp, name), arr[bounds[c]:bounds[c + 1]])
-            else:
-                np.save(os.path.join(tmp, name), arr)
-            files.append(name)
-        meta.append({"dtype": dtype_tag, "shape": list(arr.shape),
-                     "files": files})
-    with open(os.path.join(tmp, "tree.json"), "w") as f:
-        json.dump({"treedef": _treedef_token(treedef), "step": step,
-                   "leaves": meta}, f)
-    if data_state_path and os.path.exists(data_state_path):
-        shutil.copy(data_state_path, os.path.join(tmp, "data_state.npz"))
+        leaves, treedef = _flatten(tree)
+        meta: List[Dict] = []
+        for i, leaf in enumerate(leaves):
+            with spans.span("ckpt.device_get") as s:
+                arr = np.asarray(jax.device_get(leaf))
+                s.set(bytes=arr.nbytes)
+            # bf16 has no numpy dtype; persist as uint16 view + dtype tag
+            dtype_tag = str(leaf.dtype) if hasattr(leaf, "dtype") else str(arr.dtype)
+            if dtype_tag == "bfloat16":
+                arr = arr.view(np.uint16)
+            chunks = max(1, min(n_chunks, arr.shape[0] if arr.ndim else 1))
+            bounds = np.linspace(0, arr.shape[0] if arr.ndim else 1,
+                                 chunks + 1).astype(int) if arr.ndim else [0, 1]
+            files = []
+            with spans.span("ckpt.write", files=chunks) as s:
+                nbytes = 0
+                for c in range(chunks):
+                    name = f"leaf-{i:05d}.c{c:02d}.npy"
+                    with open(os.path.join(tmp, name), "wb") as f:
+                        np.save(f, arr[bounds[c]:bounds[c + 1]] if arr.ndim else arr)
+                        nbytes += f.tell()
+                    files.append(name)
+                s.set(bytes=nbytes)
+            meta.append({"dtype": dtype_tag, "shape": list(arr.shape),
+                         "files": files})
+        with spans.span("ckpt.write") as s:
+            with open(os.path.join(tmp, "tree.json"), "w") as f:
+                json.dump({"treedef": _treedef_token(treedef), "step": step,
+                           "leaves": meta}, f)
+                nbytes, nfiles = f.tell(), 1
+            if data_state_path and os.path.exists(data_state_path):
+                shutil.copy(data_state_path, os.path.join(tmp, "data_state.npz"))
+                nbytes, nfiles = nbytes + os.path.getsize(data_state_path), 2
+            s.set(bytes=nbytes, files=nfiles)
 
-    manifest = Manifest.scan(tmp)
-    manifest.save(os.path.join(tmp, "MANIFEST.json"))
-    with open(os.path.join(tmp, "COMMITTED"), "w") as f:
-        f.write("ok")
-    if os.path.exists(final):
-        shutil.rmtree(final)
-    os.rename(tmp, final)
-    _gc(ckpt_root, keep)
-    return final
+        with spans.span("ckpt.manifest") as s:
+            manifest = Manifest.scan(tmp)
+            manifest.save(os.path.join(tmp, "MANIFEST.json"))
+            s.set(bytes=manifest.total_bytes)
+        save.set(bytes=manifest.total_bytes, files=len(manifest.entries))
+        with open(os.path.join(tmp, "COMMITTED"), "w") as f:
+            f.write("ok")
+        if os.path.exists(final):
+            shutil.rmtree(final)
+        os.rename(tmp, final)
+        _gc(ckpt_root, keep)
+        return final
 
 
 def restore_checkpoint(ckpt_root: str, example_tree: PyTree,
@@ -94,33 +107,38 @@ def restore_checkpoint(ckpt_root: str, example_tree: PyTree,
     Returns (step, tree, dir) or None.  Corrupt/uncommitted candidates are
     skipped with a warning — restart never loads bad state.
     """
-    for cand_step, d in _candidates(ckpt_root, step):
-        manifest_path = os.path.join(d, "MANIFEST.json")
-        if not (os.path.exists(os.path.join(d, "COMMITTED"))
-                and os.path.exists(manifest_path)):
-            continue
-        manifest = Manifest.load(manifest_path)
-        problems = {k: v for k, v in manifest.verify(d).items()
-                    if k not in ("MANIFEST.json", "COMMITTED")}
-        if problems:
-            print(f"[ckpt] skipping corrupt {d}: {problems}")
-            continue
-        with open(os.path.join(d, "tree.json")) as f:
-            info = json.load(f)
-        leaves = []
-        for m in info["leaves"]:
-            parts = [np.load(os.path.join(d, fn)) for fn in m["files"]]
-            arr = parts[0] if len(parts) == 1 else np.concatenate(parts, 0)
-            if m["dtype"] == "bfloat16":
-                import jax.numpy as jnp
-                arr = arr.view(np.uint16)
-                leaves.append(jnp.asarray(arr).view(jnp.bfloat16))
-            else:
-                leaves.append(arr.astype(m["dtype"]))
-        _, treedef = _flatten(example_tree)
-        tree = jax.tree_util.tree_unflatten(treedef, leaves)
-        return info["step"], tree, d
-    return None
+    with spans.span("ckpt.restore", dir=ckpt_root) as restore:
+        for cand_step, d in _candidates(ckpt_root, step):
+            manifest_path = os.path.join(d, "MANIFEST.json")
+            if not (os.path.exists(os.path.join(d, "COMMITTED"))
+                    and os.path.exists(manifest_path)):
+                continue
+            manifest = Manifest.load(manifest_path)
+            with spans.span("ckpt.verify", bytes=manifest.total_bytes):
+                problems = {k: v for k, v in manifest.verify(d).items()
+                            if k not in ("MANIFEST.json", "COMMITTED")}
+            if problems:
+                print(f"[ckpt] skipping corrupt {d}: {problems}")
+                continue
+            with open(os.path.join(d, "tree.json")) as f:
+                info = json.load(f)
+            leaves = []
+            with spans.span("ckpt.read") as s:
+                for m in info["leaves"]:
+                    parts = [np.load(os.path.join(d, fn)) for fn in m["files"]]
+                    arr = parts[0] if len(parts) == 1 else np.concatenate(parts, 0)
+                    if m["dtype"] == "bfloat16":
+                        import jax.numpy as jnp
+                        arr = arr.view(np.uint16)
+                        leaves.append(jnp.asarray(arr).view(jnp.bfloat16))
+                    else:
+                        leaves.append(arr.astype(m["dtype"]))
+                s.set(bytes=sum(leaf.nbytes for leaf in leaves))
+            _, treedef = _flatten(example_tree)
+            tree = jax.tree_util.tree_unflatten(treedef, leaves)
+            restore.set(step=info["step"])
+            return info["step"], tree, d
+        return None
 
 
 def latest_step(ckpt_root: str) -> Optional[int]:

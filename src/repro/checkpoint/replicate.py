@@ -21,6 +21,7 @@ from repro.core.routes import Dataset
 from repro.core.scheduler import ReplicationPolicy, ReplicationScheduler
 from repro.core.transfer_table import Status, TransferTable
 from repro.core.transport import LocalFSTransport
+from repro.obs import spans
 
 
 @dataclass
@@ -48,26 +49,28 @@ class CheckpointReplicator:
     def replicate(self, ckpt_rel: str, max_steps: int = 1000) -> bool:
         """Replicate ``<primary>/<ckpt_rel>`` to all replicas; True if all
         copies verified."""
-        base = os.path.join(self.site_dir(self.primary), ckpt_rel.lstrip("/"))
-        nbytes = nfiles = ndirs = 0
-        for dirpath, _, files in os.walk(base):
-            ndirs += 1
-            for fn in files:
-                nfiles += 1
-                nbytes += os.path.getsize(os.path.join(dirpath, fn))
-        self.catalog[ckpt_rel] = Dataset(ckpt_rel, nbytes, nfiles, ndirs)
-        self.table.populate([ckpt_rel], self.primary, list(self.replicas))
-        now = 0.0
-        for _ in range(max_steps):
-            self.scheduler.step(now)
-            now += 1.0
-            if all((self.table.get(ckpt_rel, r) or None) is not None
-                   and self.table.get(ckpt_rel, r).status
-                   in (Status.SUCCEEDED, Status.QUARANTINED)
-                   for r in self.replicas):
-                break
-        return all(self.table.get(ckpt_rel, r).status == Status.SUCCEEDED
-                   for r in self.replicas)
+        with spans.span("ckpt.replicate") as s:
+            base = os.path.join(self.site_dir(self.primary), ckpt_rel.lstrip("/"))
+            nbytes = nfiles = ndirs = 0
+            for dirpath, _, files in os.walk(base):
+                ndirs += 1
+                for fn in files:
+                    nfiles += 1
+                    nbytes += os.path.getsize(os.path.join(dirpath, fn))
+            s.set(bytes=nbytes, files=nfiles)
+            self.catalog[ckpt_rel] = Dataset(ckpt_rel, nbytes, nfiles, ndirs)
+            self.table.populate([ckpt_rel], self.primary, list(self.replicas))
+            now = 0.0
+            for _ in range(max_steps):
+                self.scheduler.step(now)
+                now += 1.0
+                if all((self.table.get(ckpt_rel, r) or None) is not None
+                       and self.table.get(ckpt_rel, r).status
+                       in (Status.SUCCEEDED, Status.QUARANTINED)
+                       for r in self.replicas):
+                    break
+            return all(self.table.get(ckpt_rel, r).status == Status.SUCCEEDED
+                       for r in self.replicas)
 
     def restore_anywhere(self, ckpt_rel: str, example_tree,
                          step: Optional[int] = None):

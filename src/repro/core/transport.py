@@ -60,6 +60,7 @@ from repro.core.faults import (FaultInjector, FaultKind, Notifier, RetryPolicy)
 from repro.core.pause import DAY, PauseManager
 from repro.core.routes import Dataset, RouteGraph, fair_share_rates
 from repro.core.transfer_table import Status
+from repro.obs import spans
 
 
 class SimClock:
@@ -782,7 +783,8 @@ class LocalFSTransport(Transport):
         from repro.core.integrity import StreamingChecksum
         src_sum = StreamingChecksum()
         nbytes = 0
-        with open(sp, "rb") as fin, open(dp, "wb") as fout:
+        with spans.span("transport.copy") as s, \
+                open(sp, "rb") as fin, open(dp, "wb") as fout:
             while True:
                 chunk = fin.read(_CHUNK_BYTES)
                 if not chunk:
@@ -793,47 +795,55 @@ class LocalFSTransport(Transport):
                 if self.corruptor is not None:
                     payload = self.corruptor(sp, chunk)
                 fout.write(payload)
+            s.set(bytes=nbytes)
         return nbytes, src_sum.digest()
 
     @staticmethod
     def _checksum_file(path: str) -> int:
         from repro.core.integrity import stream_file_checksum
-        return stream_file_checksum(path)[1]
+        with spans.span("transport.verify") as s:
+            nbytes, csum = stream_file_checksum(path)
+            s.set(bytes=nbytes)
+        return csum
 
     def submit(self, dataset: Dataset, source: str, destination: str) -> str:
-        uid = str(uuidlib.uuid4())
-        src_base = os.path.join(self.site_dir(source), dataset.path.lstrip("/"))
-        dst_base = os.path.join(self.site_dir(destination), dataset.path.lstrip("/"))
-        faults = 0
-        nbytes = 0
-        nfiles = 0
-        ndirs = 0
-        try:
-            for dirpath, _, files in os.walk(src_base):
-                rel = os.path.relpath(dirpath, src_base)
-                ddir = os.path.join(dst_base, rel) if rel != "." else dst_base
-                os.makedirs(ddir, exist_ok=True)
-                ndirs += 1
-                for fn in files:
-                    sp = os.path.join(dirpath, fn)
-                    dp = os.path.join(ddir, fn)
-                    for _attempt in range(3):
-                        size, want = self._copy_attempt(sp, dp)
-                        if self._checksum_file(dp) == want:
-                            break
-                        faults += 1  # integrity fault -> retransmit
-                    else:
-                        raise IOError(f"persistent corruption for {sp}")
-                    nbytes += size
-                    nfiles += 1
-            st = TransferState(Status.SUCCEEDED, bytes_done=nbytes,
-                               files_done=nfiles, dirs_done=ndirs, faults=faults)
-        except (OSError, IOError) as e:
-            st = TransferState(Status.FAILED, bytes_done=nbytes,
-                               files_done=nfiles, dirs_done=ndirs,
-                               faults=faults + 1, detail=str(e))
-        self._states[uid] = st
-        return uid
+        with spans.span("transport.submit", dest=destination) as s:
+            uid = str(uuidlib.uuid4())
+            rel_path = dataset.path.lstrip("/")
+            src_base = os.path.join(self.site_dir(source), rel_path)
+            dst_base = os.path.join(self.site_dir(destination), rel_path)
+            faults = 0
+            nbytes = 0
+            nfiles = 0
+            ndirs = 0
+            try:
+                for dirpath, _, files in os.walk(src_base):
+                    rel = os.path.relpath(dirpath, src_base)
+                    ddir = os.path.join(dst_base, rel) if rel != "." else dst_base
+                    os.makedirs(ddir, exist_ok=True)
+                    ndirs += 1
+                    for fn in files:
+                        sp = os.path.join(dirpath, fn)
+                        dp = os.path.join(ddir, fn)
+                        for _attempt in range(3):
+                            size, want = self._copy_attempt(sp, dp)
+                            if self._checksum_file(dp) == want:
+                                break
+                            faults += 1  # integrity fault -> retransmit
+                        else:
+                            raise IOError(f"persistent corruption for {sp}")
+                        nbytes += size
+                        nfiles += 1
+                st = TransferState(Status.SUCCEEDED, bytes_done=nbytes,
+                                   files_done=nfiles, dirs_done=ndirs,
+                                   faults=faults)
+            except (OSError, IOError) as e:
+                st = TransferState(Status.FAILED, bytes_done=nbytes,
+                                   files_done=nfiles, dirs_done=ndirs,
+                                   faults=faults + 1, detail=str(e))
+            s.set(bytes=nbytes, files=nfiles, faults=st.faults)
+            self._states[uid] = st
+            return uid
 
     def poll(self, uid: str) -> TransferState:
         return self._states[uid]

@@ -15,8 +15,12 @@ mid-campaign.  This package gives the simulator the same layer:
     demand hit-rate;
   * ``Observability`` (``repro.obs.engine``) — the runtime wiring both onto
     a campaign, driven by ``run_world``;
+  * ``spans`` (``repro.obs.spans``) — always-on program spans at the
+    real-bytes boundaries (checkpoint save/restore, local transport, the
+    training loop), with per-name self time, also written into a running
+    ``jax.profiler`` trace;
   * ``PhaseProfiler`` (``repro.obs.profile``) — per-phase wall-time buckets
-    over the scheduler/transport/table seams;
+    over the scheduler/transport/table seams, as spans of its own recorder;
   * ``python -m repro.obs.report`` — the post-mortem CLI: days-vs-bytes
     curve, fault/outage timeline, slowest routes, most-retried datasets.
 

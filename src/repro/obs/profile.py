@@ -17,15 +17,17 @@ numbers belong alongside, never instead of, benchmark walls.
 """
 from __future__ import annotations
 
-import time
-from typing import Dict, List, Tuple
+from typing import List, Tuple
+
+from repro.obs.spans import Recorder
 
 
 class PhaseProfiler:
     """Per-phase wall-time buckets via temporary class-method wrappers.
 
-    Exclusive-time accounting: a stack tracks the active bucket, and time
-    spent in a nested instrumented call (``TransferTable`` work inside
+    Each wrapped call is a span named by its bucket in a ``Recorder`` of the
+    profiler's own, and a bucket's time is its spans' self time: time spent
+    in a nested instrumented call (``TransferTable`` work inside
     ``ReplicationScheduler.step``, say) is charged to the inner bucket and
     subtracted from the outer one, so the buckets sum to at most the run's
     wall clock and never double-count.  Wrapping happens at class level so
@@ -33,24 +35,16 @@ class PhaseProfiler:
     """
 
     def __init__(self):
-        self.buckets: Dict[str, float] = {}
-        self._stack: List[list] = []
+        self.recorder = Recorder()
         self._patched: List[Tuple[type, str, object]] = []
 
     def wrap(self, cls, name: str, bucket: str) -> None:
         orig = getattr(cls, name)
+        span = self.recorder.span
 
         def timed(s, *a, _orig=orig, _b=bucket, **kw):
-            t0 = time.perf_counter()
-            self._stack.append([_b, 0.0])
-            try:
+            with span(_b):
                 return _orig(s, *a, **kw)
-            finally:
-                dt = time.perf_counter() - t0
-                b, child = self._stack.pop()
-                self.buckets[b] = self.buckets.get(b, 0.0) + (dt - child)
-                if self._stack:
-                    self._stack[-1][1] += dt
 
         setattr(cls, name, timed)
         self._patched.append((cls, name, orig))
@@ -90,9 +84,9 @@ class PhaseProfiler:
     def report(self, wall_s: float) -> dict:
         """Bucket seconds and percentages, with the unattributed remainder
         of ``wall_s`` charged to a ``driver`` bucket."""
-        phases = {b: round(t, 3) for b, t in sorted(self.buckets.items())}
-        phases["driver"] = round(
-            max(0.0, wall_s - sum(self.buckets.values())), 3)
+        buckets = {b: t.self_seconds for b, t in self.recorder.totals().items()}
+        phases = {b: round(t, 3) for b, t in sorted(buckets.items())}
+        phases["driver"] = round(max(0.0, wall_s - sum(buckets.values())), 3)
         return {
             "wall_s": round(wall_s, 3),
             "phases_s": phases,

@@ -26,6 +26,7 @@ from repro.checkpoint.replicate import CheckpointReplicator
 from repro.data.synthetic import for_model
 from repro.models.config import ModelConfig
 from repro.models.model import LM
+from repro.obs import spans
 from repro.optim import adamw
 from repro.optim.schedule import warmup_cosine
 
@@ -134,12 +135,17 @@ def train(cfg: ModelConfig, tc: TrainConfig,
             step_fn = make_train_step(model, adamw.AdamWConfig(), tc)
 
             for step in range(start_step, tc.steps):
-                batch_np = data.batch_at(step)
-                batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+                with spans.span("train.batch", step=step):
+                    batch_np = data.batch_at(step)
+                with spans.span("train.h2d", step=step) as s:
+                    batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+                    s.set(bytes=sum(v.nbytes for v in batch_np.values()))
                 if fail_at is not None and step == fail_at:
                     fail_at = None   # fail exactly once
                     raise SimulatedFailure(f"injected failure at step {step}")
-                params, opt_state, loss, _ = step_fn(params, opt_state, batch)
+                with spans.span("train.dispatch", step=step):
+                    params, opt_state, loss, _ = step_fn(params, opt_state,
+                                                         batch)
                 losses.append(float(loss))
                 if tc.log_every and step % tc.log_every == 0:
                     print(f"[train] step {step} loss {float(loss):.4f}")

@@ -2,11 +2,14 @@
 campaign, federation, scrub), cross-process NDJSON byte identity, snapshot
 byte identity, trace ring budgeting, metrics registry semantics, transport
 flow-telemetry horizon pruning, dashboard JSON cleanliness, the phase
-profiler, and the post-mortem report CLI."""
+profiler, the post-mortem report CLI, and the program span recorder (its
+tree, self time and ring, the spans of a replicated save, and their place
+on the profiler's host plane)."""
 import dataclasses
 import json
 import math
 import os
+import shutil
 import subprocess
 import sys
 
@@ -321,3 +324,251 @@ def test_lane_engine_refuses_observed_specs():
     assert ok
     ok, reason = lane_capable(spec.with_obs(FULL_OBS))
     assert not ok and "recorder" in reason
+
+
+# ============================================================ program spans
+def test_spans_nest_per_thread_with_parent_ids():
+    """Two threads, each with a span inside a span, interleaved: every span's
+    parent is the enclosing span on its own thread."""
+    import threading
+
+    from repro.obs.spans import Recorder
+    rec = Recorder()
+    opened, inner_done = threading.Barrier(2, timeout=10), threading.Barrier(
+        2, timeout=10)
+
+    def work(tag):
+        with rec.span(f"outer.{tag}"):
+            opened.wait()
+            with rec.span(f"inner.{tag}", tag=tag):
+                pass
+            inner_done.wait()
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in "ab"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+    assert not any(t.is_alive() for t in threads)
+    by = {s.name: s for s in rec.records()}
+    assert set(by) == {"outer.a", "outer.b", "inner.a", "inner.b"}
+    for tag in "ab":
+        outer, inner = by[f"outer.{tag}"], by[f"inner.{tag}"]
+        assert outer.parent is None and inner.parent == outer.id
+        assert outer.start <= inner.start <= inner.end <= outer.end
+        assert inner.attrs == {"tag": tag}
+    assert len({s.id for s in by.values()}) == 4
+
+
+def test_span_self_time_is_duration_minus_children():
+    import time
+
+    from repro.obs.spans import Recorder
+    rec = Recorder()
+    with rec.span("parent") as s:
+        for _ in range(2):
+            with rec.span("child"):
+                time.sleep(0.002)
+        time.sleep(0.003)
+        s.set(bytes=7)
+    parent = next(r for r in rec.records() if r.name == "parent")
+    children = [r for r in rec.records() if r.name == "child"]
+    assert parent.attrs == {"bytes": 7}
+    assert all(c.parent == parent.id for c in children)
+    tot = rec.totals()
+    assert tot["child"].count == 2
+    assert tot["child"].self_seconds == tot["child"].seconds
+    assert tot["parent"].seconds == pytest.approx(parent.seconds, abs=1e-12)
+    assert tot["parent"].self_seconds == pytest.approx(
+        parent.seconds - sum(c.seconds for c in children), abs=1e-12)
+    assert 0.003 <= tot["parent"].self_seconds < parent.seconds
+
+
+def test_span_ring_drops_the_oldest_and_totals_keep_all():
+    from repro.obs.spans import CAPACITY, Recorder
+    rec = Recorder()
+    for i in range(CAPACITY + 6):
+        with rec.span(f"s{i % 2}", i=i):
+            pass
+    kept = [r.attrs["i"] for r in rec.records()]
+    assert kept == list(range(6, CAPACITY + 6))
+    assert rec.dropped == 6
+    tot = rec.totals()
+    assert tot["s0"].count == tot["s1"].count == (CAPACITY + 6) // 2
+
+
+def test_span_recorder_loses_nothing_under_many_threads():
+    """More threads than cores and a short switch interval: every span is
+    counted and either kept or counted as dropped."""
+    import threading
+
+    from repro.obs.spans import CAPACITY, Recorder
+    rec, n_threads = Recorder(), 4 * (os.cpu_count() or 2)
+    per = CAPACITY // n_threads        # twice the ring: half is dropped
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(per):
+                with rec.span("outer"):
+                    with rec.span("inner"):
+                        pass
+
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    total = 2 * n_threads * per
+    tot = rec.totals()
+    assert tot["outer"].count == tot["inner"].count == n_threads * per
+    assert len(rec.records()) + rec.dropped == total
+    assert len({r.id for r in rec.records()}) == len(rec.records())
+    recs = {r.id: r for r in rec.records()}
+    assert rec.dropped > 0
+    for r in recs.values():
+        if r.name == "inner" and r.parent in recs:
+            assert recs[r.parent].name == "outer"
+
+
+def test_spans_import_and_record_without_loading_jax():
+    """Host-only processes (the simulator's workers, a replication without
+    a training job) record spans without loading jax."""
+    code = ("import sys; sys.path.insert(0, 'src'); "
+            "from repro.obs import spans; import repro.core.transport\n"
+            "with spans.span('x', bytes=1) as s: s.set(files=1)\n"
+            "print('jax' in sys.modules, spans.totals()['x'].count)")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120, cwd=".")
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.split() == ["False", "1"]
+
+
+def _replicated_save(root, tree, step=8):
+    """One save of ``tree`` at POD0, replicated to POD1 and STORE."""
+    from repro.checkpoint.ckpt import save_checkpoint
+    from repro.checkpoint.replicate import CheckpointReplicator
+    rep = CheckpointReplicator(str(root))
+    d = save_checkpoint(os.path.join(rep.site_dir("POD0"), "ckpts"), step, tree)
+    assert rep.replicate(os.path.relpath(d, rep.site_dir("POD0")))
+    return rep, d
+
+
+def _tree(n):
+    import jax.numpy as jnp
+    import numpy as np
+    return {"w": jnp.asarray(np.arange(n * n, dtype=np.float32).reshape(n, n)),
+            "b": jnp.ones((n, n // 2), jnp.bfloat16),
+            "step": jnp.int32(3)}
+
+
+def _dir_bytes(d, skip=()):
+    return sum(os.path.getsize(os.path.join(d, f)) for f in os.listdir(d)
+               if f not in skip)
+
+
+def test_span_tree_of_one_replicated_save(tmp_path, monkeypatch):
+    """One save replicated from one source to two replicas: the tree of
+    spans, one copy and one verification per file and destination, byte
+    attributes that add up to the files on disk, and five hash passes."""
+    import numpy as np
+
+    from repro.obs import spans
+    rec = spans.Recorder()
+    monkeypatch.setattr(spans, "RECORDER", rec)
+    tree = _tree(1024)
+    rep, d = _replicated_save(tmp_path, tree)
+    recs = rec.records()
+    by_id = {r.id: r for r in recs}
+
+    def named(name):
+        return [r for r in recs if r.name == name]
+
+    (save,), (repl,) = named("ckpt.save"), named("ckpt.replicate")
+    files = sorted(os.listdir(d))
+    manifest_skip = ("MANIFEST.json", "COMMITTED")
+    state_bytes = sum(np.asarray(x).nbytes for x in tree.values())
+    # the save
+    assert save.parent is None and save.attrs["step"] == 8
+    assert len(named("ckpt.device_get")) == len(tree)
+    assert sum(r.attrs["bytes"] for r in named("ckpt.device_get")) == state_bytes
+    for name in ("ckpt.device_get", "ckpt.write", "ckpt.manifest"):
+        assert all(by_id[r.parent] is save for r in named(name))
+    (manifest,) = named("ckpt.manifest")
+    written = sum(r.attrs["bytes"] for r in named("ckpt.write"))
+    assert written == manifest.attrs["bytes"] == save.attrs["bytes"] \
+        == _dir_bytes(d, manifest_skip)
+    assert sum(r.attrs["files"] for r in named("ckpt.write")) \
+        == save.attrs["files"] == len(files) - len(manifest_skip)
+    # the replication: one submit per replica, one copy and one verify per
+    # file of each
+    submits = named("transport.submit")
+    assert sorted(s.attrs["dest"] for s in submits) == ["POD1", "STORE"]
+    assert all(by_id[s.parent] is repl for s in submits)
+    assert repl.attrs == {"bytes": _dir_bytes(d), "files": len(files)}
+    for s in submits:
+        dest = os.path.join(rep.site_dir(s.attrs["dest"]),
+                            os.path.relpath(d, rep.site_dir("POD0")))
+        assert sorted(os.listdir(dest)) == files
+        for name in ("transport.copy", "transport.verify"):
+            mine = [r for r in named(name) if r.parent == s.id]
+            assert len(mine) == len(files)
+            assert sum(r.attrs["bytes"] for r in mine) == _dir_bytes(dest)
+        assert s.attrs == {"dest": s.attrs["dest"], "bytes": _dir_bytes(dest),
+                           "files": len(files), "faults": 0}
+    hashed = sum(r.attrs["bytes"] for r in recs if r.name in (
+        "ckpt.manifest", "transport.copy", "transport.verify"))
+    assert hashed / state_bytes == pytest.approx(5.0, abs=0.01)
+    # self time: what the scheduler and the table cost inside replicate
+    tot = rec.totals()
+    assert tot["ckpt.replicate"].self_seconds == pytest.approx(
+        repl.seconds - sum(s.seconds for s in submits), abs=1e-9)
+
+
+def test_program_spans_land_on_the_profilers_host_plane(tmp_path,
+                                                         monkeypatch):
+    """Under a running ``jax.profiler`` trace, a save, its replication and
+    a restore put every program span on the trace's ``/host:CPU`` plane,
+    nested as recorded, with the recorder's durations and offsets within a
+    millisecond: the spans are on the trace's clock."""
+    import glob
+
+    import jax
+
+    from repro.obs import spans
+    rec = spans.Recorder()
+    monkeypatch.setattr(spans, "RECORDER", rec)
+    tree = _tree(64)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        rep, _ = _replicated_save(tmp_path / "sites", tree)
+        shutil.rmtree(rep.site_dir("POD0"))
+        got = rep.restore_anywhere("ckpts", tree, step=8)
+    finally:
+        jax.profiler.stop_trace()
+    assert got is not None and got[3] == "POD1"
+    recs = sorted(rec.records(), key=lambda r: r.start)
+    names = {r.name for r in recs}
+    assert {"ckpt.save", "ckpt.replicate", "transport.copy",
+            "ckpt.restore", "ckpt.verify", "ckpt.read"} <= names
+    (path,) = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+    events = sorted(((ev.start_ns, ev.end_ns, ev.name) for line in host.lines
+                     for ev in line.events if ev.name in names))
+    assert [e[2] for e in events] == [r.name for r in recs]
+    ev_of = {r.id: e for r, e in zip(recs, events)}
+    t0_ev, t0_rec = events[0][0], recs[0].start
+    for r in recs:
+        a, b, _ = ev_of[r.id]
+        assert abs((b - a) / 1e9 - r.seconds) < 1e-3, r
+        assert abs((a - t0_ev) / 1e9 - (r.start - t0_rec)) < 1e-3, r
+        if r.parent is not None:
+            pa, pb, _ = ev_of[r.parent]
+            assert pa <= a and b <= pb, r
