@@ -32,11 +32,31 @@ from repro.obs import spans
 
 PyTree = Any
 _LEAF_RE = re.compile(r"leaf-(\d{5})\.c(\d{2})\.npy$")
+_WRITE_BYTES = 4 * 1024 * 1024   # one write call; the transport's _CHUNK_BYTES
 
 
 def _flatten(tree: PyTree):
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     return leaves, treedef
+
+
+def _write_npy(f, arr: np.ndarray) -> None:
+    """Write ``arr`` to the binary file ``f`` as ``np.save`` would, byte for
+    byte (an ``.npy`` v1.0 file), with the data in one C-ordered buffer
+    written in ``_WRITE_BYTES`` slices.  ``np.save`` hands a real file to
+    ``ndarray.tofile``, which writes an array that is not C-contiguous one
+    element at a time; a TPU's ``device_get`` returns several leaves in the
+    device's layout (the embedding in Fortran order), and their chunks are
+    not contiguous."""
+    arr = np.asarray(arr)
+    header = np.lib.format.header_data_from_array_1_0(arr)
+    np.lib.format.write_array_header_1_0(f, header)
+    # np.save writes a Fortran-ordered array's memory as it lies (its
+    # header says so), any other array in C order.
+    data = np.asarray(arr.T if header["fortran_order"] else arr, order="C")
+    data = memoryview(data.reshape(-1).view(np.uint8))
+    for start in range(0, len(data), _WRITE_BYTES):
+        f.write(data[start:start + _WRITE_BYTES])
 
 
 def save_checkpoint(ckpt_root: str, step: int, tree: PyTree,
@@ -69,7 +89,7 @@ def save_checkpoint(ckpt_root: str, step: int, tree: PyTree,
                 for c in range(chunks):
                     name = f"leaf-{i:05d}.c{c:02d}.npy"
                     with open(os.path.join(tmp, name), "wb") as f:
-                        np.save(f, arr[bounds[c]:bounds[c + 1]] if arr.ndim else arr)
+                        _write_npy(f, arr[bounds[c]:bounds[c + 1]] if arr.ndim else arr)
                         nbytes += f.tell()
                     files.append(name)
                 s.set(bytes=nbytes)

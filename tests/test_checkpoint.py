@@ -7,9 +7,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.checkpoint import ckpt
 from repro.checkpoint.ckpt import (latest_step, restore_checkpoint,
                                    save_checkpoint)
 from repro.checkpoint.replicate import CheckpointReplicator
+from repro.core.integrity import Manifest
 
 
 def tree_example():
@@ -82,6 +84,96 @@ def test_replicator_restores_from_replica_when_primary_lost(tmp_path):
     step, tree, _, site = got
     assert step == 7 and site in ("POD1", "STORE")
     np.testing.assert_array_equal(np.asarray(tree["w"]), np.asarray(t["w"]))
+
+
+def _over_one_write_slice():
+    """float32 rows of 7: more bytes than one write slice, not a multiple."""
+    rows = 2 * ckpt._WRITE_BYTES // 28 + 1
+    return np.arange(rows * 7, dtype=np.float32).reshape(rows, 7)
+
+
+def _device_layout_stack():
+    return np.arange(6 * 3 * 8, dtype=np.float32).reshape(6, 3, 8
+                                                          ).transpose(0, 2, 1)
+
+
+WRITER_CASES = {
+    "bf16-as-uint16": lambda: np.asarray(
+        jnp.linspace(-3, 3, 35, dtype=jnp.bfloat16).reshape(5, 7)
+    ).view(np.uint16),
+    "float32": lambda: np.linspace(-1, 1, 24, dtype=np.float32).reshape(4, 6),
+    "int32": lambda: np.arange(-10, 14, dtype=np.int32).reshape(6, 4),
+    "scalar-0d": lambda: np.asarray(np.float32(3.25)),
+    "1d": lambda: np.arange(11, dtype=np.float32) / 7,
+    "3d-stack-slice": lambda: np.arange(6 * 3 * 5, dtype=np.float32
+                                        ).reshape(6, 3, 5)[2:4],
+    "over-one-write-slice": _over_one_write_slice,
+    "fortran-order": lambda: np.asfortranarray(
+        np.arange(6 * 10, dtype=np.float32).reshape(6, 10)),
+    "strided-view": lambda: np.arange(6 * 20, dtype=np.int32
+                                      ).reshape(6, 20)[:, ::3],
+    # a stacked leaf as a TPU's device_get returns it (last two axes in
+    # Fortran order): a one-layer chunk is Fortran-contiguous, a two-layer
+    # chunk is not contiguous at all
+    "device-layout-1-layer": lambda: _device_layout_stack()[0:1],
+    "device-layout-2-layers": lambda: _device_layout_stack()[1:3],
+}
+
+
+@pytest.mark.parametrize("case", sorted(WRITER_CASES))
+def test_write_npy_bytes_equal_np_save(tmp_path, case):
+    arr = WRITER_CASES[case]()
+    if case == "over-one-write-slice":
+        assert arr.nbytes > ckpt._WRITE_BYTES
+        assert arr.nbytes % ckpt._WRITE_BYTES
+    with open(tmp_path / "ours.npy", "wb") as f:
+        ckpt._write_npy(f, arr)
+    with open(tmp_path / "np_save.npy", "wb") as f:
+        np.save(f, arr)
+    assert (tmp_path / "ours.npy").read_bytes() == \
+        (tmp_path / "np_save.npy").read_bytes()
+    back = np.load(tmp_path / "ours.npy")
+    assert back.dtype == arr.dtype and back.shape == arr.shape
+    np.testing.assert_array_equal(back, arr)
+
+
+def every_leaf_kind():
+    return {
+        "bf16": jnp.linspace(-2, 2, 40, dtype=jnp.bfloat16).reshape(8, 5),
+        "f32": jnp.linspace(-1, 1, 24, dtype=jnp.float32).reshape(4, 6),
+        "i32": jnp.arange(-6, 6, dtype=jnp.int32).reshape(3, 4),
+        "scalar": jnp.float32(3.25),
+        "vec": jnp.arange(11, dtype=jnp.float32) / 7,
+        "stack": jnp.arange(6 * 3 * 5, dtype=jnp.float32).reshape(6, 3, 5),
+        "big": jnp.asarray(_over_one_write_slice()),
+        "fortran": np.asfortranarray(np.arange(4 * 9, dtype=np.float32
+                                               ).reshape(4, 9)),
+        "device_layout": _device_layout_stack(),
+    }
+
+
+def test_saved_files_equal_np_save_and_replicas_restore_bit_equal(
+        tmp_path, monkeypatch):
+    t = every_leaf_kind()
+    rep = CheckpointReplicator(str(tmp_path / "sites"), primary="POD0",
+                               replicas=("POD1", "STORE"))
+    ckpt_root = os.path.join(rep.site_dir("POD0"), "ckpts")
+    d = save_checkpoint(ckpt_root, 3, t)
+    with monkeypatch.context() as m:
+        m.setattr(ckpt, "_write_npy", lambda f, arr: np.save(f, arr))
+        d_ref = save_checkpoint(str(tmp_path / "np_save"), 3, t)
+    assert Manifest.scan(d).entries == Manifest.scan(d_ref).entries
+
+    assert rep.replicate(os.path.relpath(d, rep.site_dir("POD0")))
+    shutil.rmtree(ckpt_root)
+    got = rep.restore_anywhere("ckpts", t)
+    assert got is not None
+    step, tree, _, site = got
+    assert step == 3 and site in ("POD1", "STORE")
+    for name, leaf in t.items():
+        back = tree[name]
+        assert back.dtype == leaf.dtype and back.shape == leaf.shape, name
+        assert np.asarray(back).tobytes() == np.asarray(leaf).tobytes(), name
 
 
 def test_elastic_reshard_plan():
