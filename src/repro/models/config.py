@@ -23,6 +23,17 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (``rope_scaling`` of type ``yarn``)."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class MoEConfig:
     n_routed: int = 64
     top_k: int = 6
@@ -32,6 +43,16 @@ class MoEConfig:
     first_dense_layers: int = 0    # leading dense layers (deepseek-v2)
     d_ff_dense: int = 0            # ffn width of those dense layers
     router_norm_topk: bool = True  # normalize top-k weights to sum to 1
+    # experts [first_held, first_held + n_held) live here; the router keeps
+    # all n_routed outputs (expert parallelism's share of one chip)
+    n_held: Optional[int] = None   # None: every routed expert
+    first_held: int = 0
+    seq_aux: bool = False          # balance loss per sequence, not per batch
+    aux_weight: float = 0.01       # weight of the balance loss in the loss
+
+    @property
+    def held(self) -> int:
+        return self.n_routed if self.n_held is None else self.n_held
 
 
 @dataclass(frozen=True)
@@ -68,6 +89,7 @@ class ModelConfig:
     sliding_window: Optional[int] = None      # window size for local layers
     local_global_ratio: int = 0               # N local : 1 global (0 = all global)
     mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnScaling] = None  # MLA's rope dims only
     mrope: bool = False                       # 3-section M-RoPE (qwen2-vl)
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     # --- mixture / ssm / hybrid -------------------------------------------
@@ -213,11 +235,13 @@ def param_count(cfg: ModelConfig) -> Tuple[int, int]:
             continue
         if cfg.moe is not None and i >= cfg.moe.first_dense_layers:
             m = cfg.moe
-            routed = m.n_routed * 3 * d * m.d_ff_expert
+            routed = m.held * 3 * d * m.d_ff_expert
             shared = m.n_shared * 3 * d * m.d_ff_expert
             router = d * m.n_routed
             total += routed + shared + router
-            active += (m.top_k + m.n_shared) * 3 * d * m.d_ff_expert + router
+            # the held experts' share of the top-k choices
+            active += ((m.top_k * m.held // m.n_routed + m.n_shared)
+                       * 3 * d * m.d_ff_expert + router)
         elif cfg.moe is not None:
             total += mlp_params(cfg.moe.d_ff_dense)
             active += mlp_params(cfg.moe.d_ff_dense)

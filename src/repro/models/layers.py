@@ -14,9 +14,10 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.axes import constrain
-from repro.models.config import ModelConfig, MLAConfig
+from repro.models.config import ModelConfig, MLAConfig, YarnScaling
 
 Params = dict
 NEG_INF = -1e30
@@ -45,12 +46,51 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, s: YarnScaling):
+    """The pair indices between which YaRN ramps from the original to the
+    interpolated frequencies (DeepSeek-V2's ``yarn_find_correction_range``)."""
+    def at(rotations):
+        return (dim * math.log(s.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(at(s.beta_fast)), 0),
+            min(math.ceil(at(s.beta_slow)), dim - 1))
+
+
+def yarn_freqs(dim: int, theta: float, s: YarnScaling) -> jnp.ndarray:
+    """YaRN inverse frequencies: the original ones below the correction
+    range, those divided by ``factor`` above it, a linear ramp between."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_correction_range(dim, theta, s)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp
+    return jnp.asarray(extra / s.factor * (1 - keep) + extra * keep, jnp.float32)
+
+
+def yarn_softmax_scale(s: YarnScaling) -> float:
+    """YaRN's factor on the attention softmax scale: mscale(mscale_all_dim)^2."""
+    return yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling: Optional[YarnScaling] = None) -> jnp.ndarray:
     """x: (B, T, H, hd); positions: (B, T) -> rotated x (same dtype)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if scaling is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
+    else:
+        freqs = yarn_freqs(hd, theta, scaling)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, T, hd/2)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -280,19 +320,25 @@ def mla_attention(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                   ) -> Tuple[jnp.ndarray, Optional[MLACache]]:
     """Multi-head Latent Attention (DeepSeek-V2).  Caches the 512-d latent
     + shared rope key instead of per-head K/V (the paper's KV-cache saving)."""
+    with jax.named_scope("mla.attention"):
+        return _mla_attention(p, cfg, x, positions, cache, cache_index)
+
+
+def _mla_attention(p, cfg, x, positions, cache, cache_index):
     m: MLAConfig = cfg.mla
     B, T, D = x.shape
     H = cfg.n_heads
     nope, rope_d, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+    yarn = cfg.rope_scaling
 
     q = constrain((x @ p["wq"]).reshape(B, T, H, nope + rope_d),
                   ("batch", "seq", "heads", None))
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, yarn)
 
     c_kv = rmsnorm(p["kv_norm"], x @ p["w_dkv"], cfg.norm_eps)   # (B,T,r)
     k_rope_new = apply_rope((x @ p["w_krope"])[:, :, None, :],
-                            positions, cfg.rope_theta)[:, :, 0, :]  # (B,T,rope_d)
+                            positions, cfg.rope_theta, yarn)[:, :, 0, :]
 
     if cache is not None:
         c_kv_full = jax.lax.dynamic_update_slice(
@@ -316,6 +362,8 @@ def mla_attention(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     vv = constrain((c_kv_full @ p["w_uv"]).reshape(B, S, H, vd),
                    ("batch", "seq", "heads", None))
     scale = (nope + rope_d) ** -0.5
+    if yarn is not None:
+        scale *= yarn_softmax_scale(yarn)
 
     def mla_block(qn, qr, qp):
         Tq = qn.shape[1]

@@ -13,6 +13,7 @@ Modes
 from __future__ import annotations
 
 import functools
+import operator
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -47,7 +48,8 @@ def init_attn_block(key, cfg: ModelConfig, use_moe: bool, dense_ff: int = 0,
 
 def attn_block(p: Params, cfg: ModelConfig, x, positions, cache=None,
                cache_index=None, window=None, positions3=None, use_moe=False):
-    """Pre-norm transformer block.  Returns (x, new_cache, aux_loss)."""
+    """Pre-norm transformer block.  Returns (x, new_cache, aux): a dense
+    block's balance loss (0.0), or an expert block's stats (``moe_forward``)."""
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if cfg.mla is not None:
         a, new_cache = L.mla_attention(p["attn"], cfg, h, positions, cache, cache_index)
@@ -309,13 +311,16 @@ class LM:
 
         if pat.kind in ("uniform_attn", "moe"):
             use_moe = pat.kind == "moe"
+            acc = MOE.add_stats if use_moe else operator.add
+            if use_moe:
+                aux0 = MOE.zero_stats()
             if pat.n_lead:
                 lead_caches = cache["lead"] if serving else [None] * pat.n_lead
                 new_lead = []
                 for i, lp in enumerate(params["lead"]):
-                    x, nc, a = attn_block(lp, cfg, x, positions, lead_caches[i],
+                    # a leading dense block: no balance loss, no counters
+                    x, nc, _ = attn_block(lp, cfg, x, positions, lead_caches[i],
                                           t, None, positions3, use_moe=False)
-                    aux0 = aux0 + a
                     new_lead.append(nc)
                 if serving:
                     new_cache["lead"] = new_lead
@@ -332,7 +337,7 @@ class LM:
                     x, nc, a = attn_block(bp, cfg, x, positions, bc, t,
                                           cfg.sliding_window, positions3,
                                           use_moe)
-                    aux0 = aux0 + a
+                    aux0 = acc(aux0, a)
                     stacked = jax.tree_util.tree_map(
                         lambda full, upd, i=i: full.at[i].set(
                             upd.astype(full.dtype)), stacked, nc)
@@ -343,7 +348,7 @@ class LM:
                     bp, bc = layer
                     y, nc, a = attn_block(bp, cfg, xx, positions, bc, t,
                                           cfg.sliding_window, positions3, use_moe)
-                    return (y, aux + a), nc
+                    return (y, acc(aux, a)), nc
                 (x, aux0), ncs = jax.lax.scan(
                     body, (x, aux0), (params["blocks"], cache["blocks"]))
                 new_cache["blocks"] = ncs
@@ -352,7 +357,7 @@ class LM:
                     xx, aux = carry
                     y, _, a = attn_block(bp, cfg, xx, positions, None, None,
                                          cfg.sliding_window, positions3, use_moe)
-                    return (y, aux + a), None
+                    return (y, acc(aux, a)), None
                 (x, aux0), _ = jax.lax.scan(
                     self._maybe_remat(body, mode), (x, aux0), params["blocks"])
 
@@ -464,6 +469,9 @@ class LM:
     # ------------------------------------------------------------------ loss
     def loss_fn(self, params: Params, batch: Dict[str, jnp.ndarray],
                 aux_weight: float = 0.01) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+        """Cross-entropy plus the weighted balance loss.  An expert model
+        weighs its balance loss by ``moe.aux_weight`` and returns its held
+        experts' counters as ``metrics["moe"]``."""
         cfg = self.cfg
         x = self.embed(params, batch)
         x = constrain(x, ("batch", "seq", None))
@@ -475,8 +483,12 @@ class LM:
         logits = self.unembed(params, x)
         labels = batch["labels"]
         ce = softmax_xent(logits, labels)
+        counters = {}
+        if isinstance(aux, dict):
+            counters["moe"] = {k: v for k, v in aux.items() if k != "aux"}
+            aux, aux_weight = aux["aux"], cfg.moe.aux_weight
         loss = ce + aux_weight * aux
-        return loss, {"ce": ce, "aux": aux}
+        return loss, {"ce": ce, "aux": aux, **counters}
 
     # --------------------------------------------------------------- serving
     def prefill(self, params: Params, batch: Dict[str, jnp.ndarray],

@@ -96,6 +96,8 @@ def make_train_step(model: LM, opt_cfg: adamw.AdamWConfig,
                            train_cfg.warmup, train_cfg.steps)
         params, opt_state, opt_metrics = adamw.update(
             grads, opt_state, lr, opt_cfg)
+        if "moe" in metrics:            # an expert model's counters
+            opt_metrics = {**opt_metrics, "moe": metrics["moe"]}
         return params, opt_state, loss, opt_metrics
 
     return jax.jit(step, donate_argnums=(0, 1))
@@ -144,9 +146,13 @@ def train(cfg: ModelConfig, tc: TrainConfig,
                     fail_at = None   # fail exactly once
                     raise SimulatedFailure(f"injected failure at step {step}")
                 with spans.span("train.dispatch", step=step):
-                    params, opt_state, loss, _ = step_fn(params, opt_state,
-                                                         batch)
+                    params, opt_state, loss, out = step_fn(params, opt_state,
+                                                           batch)
                 losses.append(float(loss))
+                if "moe" in out:
+                    with spans.span("train.moe", step=step) as s:
+                        s.set(**{k: int(v) for k, v in
+                                 jax.device_get(out["moe"]).items()})
                 if tc.log_every and step % tc.log_every == 0:
                     print(f"[train] step {step} loss {float(loss):.4f}")
                 next_step = step + 1

@@ -104,14 +104,19 @@ def test_prefill_decode_consistency(arch, rng_key):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_param_count_matches_instantiated(arch, rng_key):
     """Analytic param_count (used for roofline MODEL_FLOPS) must match the
-    actually instantiated smoke model within 2%."""
+    actually instantiated smoke model within 2%; an expert model's too when
+    it holds a share of its routed experts."""
     cfg = get_config(arch).smoke()
-    model = LM(cfg, remat=False)
-    params = model.init(rng_key)
-    actual = sum(int(np.prod(x.shape))
-                 for x in jax.tree_util.tree_leaves(params))
-    predicted, _ = param_count(cfg)
-    assert abs(actual - predicted) / actual < 0.02, (arch, actual, predicted)
+    cfgs = [cfg]
+    if cfg.moe is not None:
+        cfgs.append(cfg.with_(moe=dataclasses.replace(
+            cfg.moe, n_held=cfg.moe.n_routed // 4, first_held=2)))
+    for c in cfgs:
+        params = LM(c, remat=False).init(rng_key)
+        actual = sum(int(np.prod(x.shape))
+                     for x in jax.tree_util.tree_leaves(params))
+        predicted, _ = param_count(c)
+        assert abs(actual - predicted) / actual < 0.02, (arch, actual, predicted)
 
 
 def test_sliding_window_masks_history(rng_key):

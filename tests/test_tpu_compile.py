@@ -93,3 +93,45 @@ def test_full_width_train_step_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used <= V5E_HBM_BYTES, used
+
+
+def test_moe_mla_train_step_fits_one_chip(one_chip):
+    """The moe-mla-train cell's step (DeepSeek-V2-Lite, 5 layers, 8 held of
+    64 experts, a 12,800-row vocabulary) at 2 x 4096 tokens with per-layer
+    recomputation, as its configuration file and traffic state."""
+    import importlib.util
+    import json
+    import os
+    from repro.models.model import LM
+    from repro.optim import adamw
+    from repro.train.loop import TrainConfig, make_train_step
+
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmarks", "chip")
+    spec = importlib.util.spec_from_file_location(
+        "moe_train_job", os.path.join(bench, "drivers", "moe_train_job.py"))
+    drv = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(drv)
+    with open(os.path.join(bench, "configs", "deepseek-v2-lite-ep8.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(bench, "workloads", "moe-mla-train.json")) as f:
+        t = json.load(f)
+    assert cfg["remat"] and (t["batch"], t["seq"]) == (2, 4096)
+    model = LM(drv.model_config(cfg), remat=cfg["remat"])
+    tc = TrainConfig(steps=cfg["optimizer"]["total_steps"], batch_size=t["batch"],
+                     seq_len=t["seq"], remat=cfg["remat"])
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) == 535_060_992
+    opt = jax.eval_shape(adamw.init, params)
+    batch = {k: _sds((t["batch"], t["seq"]), jnp.int32, one_chip)
+             for k in ("tokens", "labels")}
+    compiled = make_train_step(model, adamw.AdamWConfig(), tc).lower(
+        place(params), place(opt), batch).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used <= V5E_HBM_BYTES, used
