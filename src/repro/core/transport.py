@@ -50,7 +50,9 @@ from __future__ import annotations
 
 import abc
 import os
+import threading
 import uuid as uuidlib
+from concurrent.futures import CancelledError, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -755,6 +757,15 @@ class SimulatedTransport(Transport):
 
 # ================================================================== local FS
 _CHUNK_BYTES = 4 * 1024 * 1024
+_MAX_WORKERS = 8        # files of one transfer in flight at once, at most
+
+
+class _PersistentCorruption(IOError):
+    """Every attempt at one file's copy failed its verification."""
+
+    def __init__(self, path: str, faults: int):
+        super().__init__(f"persistent corruption for {path}")
+        self.faults = faults
 
 
 class LocalFSTransport(Transport):
@@ -764,14 +775,21 @@ class LocalFSTransport(Transport):
     ``root/src/P`` -> ``root/dst/P`` file by file in ``_CHUNK_BYTES`` pieces,
     checksumming source and destination incrementally as the bytes stream
     through (paper: Globus checksums every file and retransmits corrupted
-    ones) — whole files are never held in memory.  ``corruptor`` lets tests
-    flip bytes in flight (it sees each chunk) to prove detection.
+    ones) — whole files are never held in memory.  Like a Globus task's
+    concurrency, several files of one transfer are in flight at once: a pool
+    of up to ``_MAX_WORKERS`` threads, no more than the host's cores, the
+    transfer's files or its ``_CHUNK_BYTES`` chunks, takes them largest
+    first; file I/O and the numpy hash run outside the interpreter lock.
+    ``submit`` returns when every worker has stopped.  ``corruptor`` lets
+    tests flip bytes in flight (it sees each chunk, one call at a time) to
+    prove detection.
     """
 
     def __init__(self, root: str,
                  corruptor: Optional[Callable[[str, bytes], bytes]] = None):
         self.root = root
         self.corruptor = corruptor
+        self._corruptor_lock = threading.Lock()
         self._states: Dict[str, TransferState] = {}
 
     def site_dir(self, site: str) -> str:
@@ -793,7 +811,8 @@ class LocalFSTransport(Transport):
                 src_sum.update(chunk)
                 payload = chunk
                 if self.corruptor is not None:
-                    payload = self.corruptor(sp, chunk)
+                    with self._corruptor_lock:
+                        payload = self.corruptor(sp, chunk)
                 fout.write(payload)
             s.set(bytes=nbytes)
         return nbytes, src_sum.digest()
@@ -806,41 +825,91 @@ class LocalFSTransport(Transport):
             s.set(bytes=nbytes)
         return csum
 
+    def _move_file(self, sp: str, dp: str) -> Tuple[int, int]:
+        """Copy one file and verify the copy, retransmitting on a mismatch;
+        returns (nbytes, integrity faults)."""
+        for attempt in range(3):
+            size, want = self._copy_attempt(sp, dp)
+            if self._checksum_file(dp) == want:
+                return size, attempt
+        raise _PersistentCorruption(sp, faults=3)
+
+    def _move_file_under(self, parent, sp: str, dp: str) -> Tuple[int, int]:
+        with spans.adopt(parent):
+            return self._move_file(sp, dp)
+
+    def _move_files(self, files: List[Tuple[int, str, str]], parent
+                    ) -> Tuple[int, int, int, Optional[OSError]]:
+        """Move ``(size, source, destination)`` files in their order;
+        returns the bytes, files and faults of those that finished, and the
+        first failure, after which no file not yet started is begun.  No
+        more workers than the transfer has chunks: over small files the
+        passes are syscalls and interpreter work, which threads slow down
+        rather than overlap."""
+        nbytes = nfiles = faults = 0
+        chunks = -(-sum(size for size, _, _ in files) // _CHUNK_BYTES)
+        workers = min(len(files), os.cpu_count() or 1, _MAX_WORKERS, chunks)
+        if workers <= 1:
+            for _, sp, dp in files:
+                try:
+                    size, nf = self._move_file(sp, dp)
+                except OSError as e:
+                    return nbytes, nfiles, faults + getattr(e, "faults", 0), e
+                nbytes, nfiles, faults = nbytes + size, nfiles + 1, faults + nf
+            return nbytes, nfiles, faults, None
+        error = None
+        pool = ThreadPoolExecutor(workers, thread_name_prefix="transport")
+        try:
+            futures = [pool.submit(self._move_file_under, parent, sp, dp)
+                       for _, sp, dp in files]
+            for f in futures:
+                try:
+                    size, nf = f.result()
+                except CancelledError:
+                    continue
+                except OSError as e:
+                    faults += getattr(e, "faults", 0)
+                    if error is None:
+                        error = e
+                        for g in futures:
+                            g.cancel()
+                    continue
+                nbytes, nfiles, faults = nbytes + size, nfiles + 1, faults + nf
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
+        return nbytes, nfiles, faults, error
+
     def submit(self, dataset: Dataset, source: str, destination: str) -> str:
         with spans.span("transport.submit", dest=destination) as s:
             uid = str(uuidlib.uuid4())
             rel_path = dataset.path.lstrip("/")
             src_base = os.path.join(self.site_dir(source), rel_path)
             dst_base = os.path.join(self.site_dir(destination), rel_path)
-            faults = 0
-            nbytes = 0
-            nfiles = 0
-            ndirs = 0
+            nbytes = nfiles = faults = ndirs = 0
+            error = None
             try:
-                for dirpath, _, files in os.walk(src_base):
+                files = []
+                for dirpath, _, names in os.walk(src_base):
                     rel = os.path.relpath(dirpath, src_base)
                     ddir = os.path.join(dst_base, rel) if rel != "." else dst_base
                     os.makedirs(ddir, exist_ok=True)
                     ndirs += 1
-                    for fn in files:
+                    for fn in names:
                         sp = os.path.join(dirpath, fn)
-                        dp = os.path.join(ddir, fn)
-                        for _attempt in range(3):
-                            size, want = self._copy_attempt(sp, dp)
-                            if self._checksum_file(dp) == want:
-                                break
-                            faults += 1  # integrity fault -> retransmit
-                        else:
-                            raise IOError(f"persistent corruption for {sp}")
-                        nbytes += size
-                        nfiles += 1
+                        files.append((os.path.getsize(sp), sp,
+                                      os.path.join(ddir, fn)))
+                files.sort(key=lambda f: -f[0])       # largest first
+                nbytes, nfiles, faults, error = self._move_files(files, s)
+            except OSError as e:
+                error = e
+            if error is None:
                 st = TransferState(Status.SUCCEEDED, bytes_done=nbytes,
                                    files_done=nfiles, dirs_done=ndirs,
                                    faults=faults)
-            except (OSError, IOError) as e:
+            else:
                 st = TransferState(Status.FAILED, bytes_done=nbytes,
                                    files_done=nfiles, dirs_done=ndirs,
-                                   faults=faults + 1, detail=str(e))
+                                   faults=faults + 1, detail=str(error))
             s.set(bytes=nbytes, files=nfiles, faults=st.faults)
             self._states[uid] = st
             return uid

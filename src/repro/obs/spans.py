@@ -21,7 +21,17 @@ belong at boundaries that move bytes, never in a per-tick loop of the
 simulator (``PhaseProfiler`` wraps those on demand, in a recorder of its
 own).  A span's parent is the innermost span open on the same thread, so a
 save run on a background thread keeps its own tree.  A span's self time is
-its duration less the durations of its children.
+its duration less the durations of its children on its own thread.
+
+Work handed to a pool of threads stays in its caller's tree through
+``adopt``: a worker opens its spans under a span open on another thread.
+
+    with spans.span("transport.submit") as s:
+        pool.submit(work, s)                # work runs `with spans.adopt(s):`
+
+Such a child carries its parent's id but does not subtract from the parent's
+self time, since the two overlap in wall time and several children may run at
+once.  Under a trace, a worker's annotations land on its own host thread.
 
 jax is not imported here: the annotation is taken from jax once the process
 has loaded it, since a process that never loads jax runs no profiler.
@@ -30,11 +40,12 @@ Host-only simulator workers import this module without loading jax.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import sys
 import threading
 import time
-from typing import Any, Dict, List, NamedTuple, Optional
+from typing import Any, Dict, Iterator, List, NamedTuple, Optional
 
 CAPACITY = 16_384
 
@@ -45,7 +56,8 @@ class Span(NamedTuple):
     start: float                    # time.perf_counter(), seconds
     end: float
     id: int
-    parent: Optional[int]           # id of the enclosing span on its thread
+    parent: Optional[int]           # id of the enclosing span on its thread,
+                                    # or of the span it was adopted under
     attrs: Dict[str, Any]
 
     @property
@@ -129,6 +141,16 @@ class OpenSpan:
                 t[2] += seconds - self._child_s
 
 
+class _Adopted:
+    """A worker thread's stand-in under ``adopt`` for a span open on another
+    thread: it gives the worker's spans their parent id, and the seconds
+    they add to its children are counted nowhere."""
+    __slots__ = ("id", "_child_s")
+
+    def __init__(self, span_id: int):
+        self.id, self._child_s = span_id, 0.0
+
+
 class Recorder:
     """Spans in a bounded ring plus per-name totals; thread-safe."""
 
@@ -164,6 +186,22 @@ def span(name: str, **attrs) -> OpenSpan:
 
 def records() -> List[Span]:
     return RECORDER.records()
+
+
+@contextlib.contextmanager
+def adopt(parent: OpenSpan) -> Iterator[None]:
+    """Open this thread's spans of ``parent``'s recorder under ``parent``, a
+    span open on another thread."""
+    local = parent._rec._local
+    try:
+        stack = local.stack
+    except AttributeError:
+        stack = local.stack = []
+    stack.append(_Adopted(parent.id))
+    try:
+        yield
+    finally:
+        stack.pop()
 
 
 def totals() -> Dict[str, Total]:

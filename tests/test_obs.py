@@ -434,6 +434,77 @@ def test_span_recorder_loses_nothing_under_many_threads():
             assert recs[r.parent].name == "outer"
 
 
+def _adopted_child(rec, parent, hold=0.02):
+    """A span ``child`` opened on a new thread under ``parent``, then a
+    ``loose`` span on the same thread after the adoption ends."""
+    import threading
+    import time
+
+    from repro.obs import spans
+
+    def work():
+        with spans.adopt(parent):
+            with rec.span("child", k=1):
+                with rec.span("grandchild"):
+                    time.sleep(hold)
+        with rec.span("loose"):
+            pass
+
+    t = threading.Thread(target=work)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+
+
+def test_adopted_span_carries_its_parents_id():
+    from repro.obs.spans import Recorder
+    rec = Recorder()
+    with rec.span("parent") as p:
+        _adopted_child(rec, p)
+    by = {r.name: r for r in rec.records()}
+    assert by["parent"].parent is None
+    assert by["child"].parent == by["parent"].id and by["child"].attrs == {"k": 1}
+    assert by["grandchild"].parent == by["child"].id
+    assert by["loose"].parent is None            # the adoption has ended
+    assert by["parent"].start <= by["child"].start <= by["child"].end \
+        <= by["parent"].end
+
+
+def test_adopted_span_leaves_its_parents_self_time():
+    """A child on another thread overlaps its parent in wall time: the
+    parent's self time stays its whole duration, while the child's own
+    child still counts against the child."""
+    import time
+
+    from repro.obs.spans import Recorder
+    rec = Recorder()
+    with rec.span("parent") as p:
+        _adopted_child(rec, p, hold=0.03)
+        time.sleep(0.002)
+    by = {r.name: r for r in rec.records()}
+    tot = rec.totals()
+    assert tot["parent"].self_seconds == pytest.approx(by["parent"].seconds,
+                                                       abs=1e-12)
+    assert tot["parent"].self_seconds >= 0.03
+    assert tot["child"].self_seconds == pytest.approx(
+        by["child"].seconds - by["grandchild"].seconds, abs=1e-12)
+
+
+def test_adopted_spans_are_counted_in_the_totals():
+    from repro.obs.spans import Recorder
+    rec = Recorder()
+    with rec.span("parent") as p:
+        for _ in range(3):
+            _adopted_child(rec, p, hold=0.001)
+    children = [r for r in rec.records() if r.name == "child"]
+    tot = rec.totals()
+    assert tot["child"].count == 3 and tot["grandchild"].count == 3
+    assert tot["loose"].count == 3 and tot["parent"].count == 1
+    assert tot["child"].seconds == pytest.approx(
+        sum(c.seconds for c in children), abs=1e-12)
+    assert {c.parent for c in children} == {p.id}
+
+
 def test_spans_import_and_record_without_loading_jax():
     """Host-only processes (the simulator's workers, a replication without
     a training job) record spans without loading jax."""

@@ -182,6 +182,173 @@ def test_localfs_transport_detects_and_retransmits_corruption(tmp_path):
         assert f.read() == b"payload" * 100
 
 
+def _many_files(root, n=48, seed=0):
+    """``n`` files under ``root/A/many`` in nested directories: empty, 1 and 3
+    bytes, sizes that are not a multiple of 4, and two over a copy chunk;
+    returns their paths relative to the dataset, largest first."""
+    from repro.core.transport import _CHUNK_BYTES
+    rng = np.random.default_rng(seed)
+    sizes = [0, 1, 3, 4097, _CHUNK_BYTES + 3, 2 * _CHUNK_BYTES + 1]
+    sizes += [int(x) for x in rng.integers(5, 300_000, n - len(sizes))]
+    rels = []
+    for i, size in enumerate(sizes):
+        rel = os.path.join(*[f"d{i % k}" for k in (2, 3, 5)][:i % 4], f"f{i}.bin")
+        path = os.path.join(root, "A", "many", rel)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "wb") as f:
+            f.write(rng.bytes(size))
+        rels.append(rel)
+    ds = Dataset("many", sum(sizes), n, 1)
+    return ds, [r for _, r in sorted(zip(sizes, rels), reverse=True)]
+
+
+def _same_bytes(root, rel, site="B", dataset="many"):
+    with open(os.path.join(root, "A", dataset, rel), "rb") as f:
+        want = f.read()
+    with open(os.path.join(root, site, dataset, rel), "rb") as f:
+        return f.read() == want
+
+
+def _threads_of_moves(monkeypatch):
+    """Record the thread that moves each file."""
+    import threading
+    seen = []
+    move = LocalFSTransport._move_file
+
+    def spy(self, sp, dp):
+        seen.append(threading.get_ident())
+        return move(self, sp, dp)
+
+    monkeypatch.setattr(LocalFSTransport, "_move_file", spy)
+    return seen
+
+
+def _pool_threads():
+    import threading
+    return [t for t in threading.enumerate() if t.name.startswith("transport")]
+
+
+def test_localfs_transport_pool_moves_many_files(tmp_path, monkeypatch):
+    """Every file byte-equal, moved on pool threads whose copy and verify
+    spans keep the transfer's span as their parent."""
+    import threading
+
+    from repro.obs import spans
+    rec = spans.Recorder()
+    monkeypatch.setattr(spans, "RECORDER", rec)
+    root = str(tmp_path)
+    ds, rels = _many_files(root)
+    seen = _threads_of_moves(monkeypatch)
+    tr = LocalFSTransport(root)
+    st = tr.poll(tr.submit(ds, "A", "B"))
+    assert st.status == Status.SUCCEEDED
+    assert (st.files_done, st.bytes_done, st.faults) == (len(rels), ds.bytes, 0)
+    assert all(_same_bytes(root, r) for r in rels)
+    assert len(seen) == len(rels)
+    assert len(set(seen)) > 1 and threading.get_ident() not in seen
+    assert not _pool_threads()
+    (submit,) = [r for r in rec.records() if r.name == "transport.submit"]
+    for name in ("transport.copy", "transport.verify"):
+        mine = [r for r in rec.records() if r.name == name]
+        assert len(mine) == len(rels)
+        assert all(r.parent == submit.id for r in mine)
+        assert sum(r.attrs["bytes"] for r in mine) == ds.bytes
+    assert rec.totals()["transport.submit"].self_seconds == pytest.approx(
+        submit.seconds, abs=1e-12)
+
+
+def test_localfs_transport_pool_retransmits_first_attempts(tmp_path,
+                                                           monkeypatch):
+    """A corruptor keyed by path flips a byte in the first attempt of every
+    third file, with more workers than cores and the interpreter switching
+    threads as often as it can: each hit costs one fault and one
+    retransmission, and the corruptor, which keeps no lock of its own, is
+    called once per chunk of every attempt."""
+    import sys
+    import time
+
+    import repro.core.transport as transport
+    root = str(tmp_path)
+    monkeypatch.setattr(transport, "_CHUNK_BYTES", 4096)
+    monkeypatch.setattr(transport, "_MAX_WORKERS", 64)
+    monkeypatch.setattr(transport.os, "cpu_count", lambda: 64)
+    ds, rels = _many_files(root, n=60, seed=1)
+    sizes = {r: os.path.getsize(os.path.join(root, "A", "many", r))
+             for r in rels}
+    hit = {os.path.join(root, "A", "many", r)
+           for r in sorted(rels)[::3] if sizes[r]}
+    flipped, calls = set(), {"n": 0}
+
+    def corruptor(path, data):
+        n = calls["n"]
+        time.sleep(0)                         # another worker may run here
+        calls["n"] = n + 1                    # and its update be lost
+        if path in hit and path not in flipped:
+            flipped.add(path)
+            return bytes([data[0] ^ 0xFF]) + data[1:]
+        return data
+
+    seen = _threads_of_moves(monkeypatch)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        tr = LocalFSTransport(root, corruptor=corruptor)
+        st = tr.poll(tr.submit(ds, "A", "B"))
+    finally:
+        sys.setswitchinterval(old)
+    chunks = {p: -(-sizes[r] // 4096)
+              for r in rels for p in [os.path.join(root, "A", "many", r)]}
+    assert st.status == Status.SUCCEEDED
+    assert flipped == hit and st.faults == len(hit) > 10
+    assert (st.files_done, st.bytes_done) == (len(rels), ds.bytes)
+    assert calls["n"] == sum(chunks.values()) + sum(chunks[p] for p in hit)
+    assert all(_same_bytes(root, r) for r in rels)
+    assert len(set(seen)) > 1
+    assert not _pool_threads()
+
+
+def test_localfs_transport_pool_fails_on_persistent_corruption(tmp_path):
+    """One file among many is corrupted on every attempt: the transfer
+    fails, counts what finished, and leaves no worker running."""
+    root = str(tmp_path)
+    ds, rels = _many_files(root)
+    bad = os.path.join(root, "A", "many", rels[len(rels) // 2])
+
+    def corruptor(path, data):
+        return data[:-1] + bytes([data[-1] ^ 1]) if path == bad else data
+
+    tr = LocalFSTransport(root, corruptor=corruptor)
+    st = tr.poll(tr.submit(ds, "A", "B"))
+    assert not _pool_threads()
+    assert st.status == Status.FAILED
+    assert "persistent corruption" in st.detail and bad in st.detail
+    assert st.faults == 3 + 1                 # three attempts, then the failure
+    assert st.files_done < len(rels) and st.bytes_done < ds.bytes
+    assert not _same_bytes(root, rels[len(rels) // 2])
+
+
+def test_localfs_transport_one_file_opens_no_pool(tmp_path, monkeypatch):
+    import threading
+
+    import repro.core.transport as transport
+
+    def no_pool(*a, **kw):
+        raise AssertionError("a one-file transfer opened a pool")
+
+    monkeypatch.setattr(transport, "ThreadPoolExecutor", no_pool)
+    root = str(tmp_path)
+    os.makedirs(os.path.join(root, "A", "one"))
+    data = np.random.default_rng(2).bytes(3 * transport._CHUNK_BYTES + 1)
+    with open(os.path.join(root, "A", "one", "f.bin"), "wb") as f:
+        f.write(data)
+    seen = _threads_of_moves(monkeypatch)
+    tr = LocalFSTransport(root)
+    st = tr.poll(tr.submit(Dataset("one", len(data), 1, 1), "A", "B"))
+    assert st.status == Status.SUCCEEDED and st.bytes_done == len(data)
+    assert seen == [threading.get_ident()]
+    assert _same_bytes(root, "f.bin", dataset="one")
+
+
 # -------------------------------------------------------------- incremental
 def test_incremental_replication_picks_up_new_datasets():
     _, catalog, clock, _, transport, table, sched, _ = small_world(4)
