@@ -7,10 +7,10 @@ Validates against the paper's own numbers:
   * per-route average rates in the neighborhood of Table 3;
   * fault skew: most transfers fault-free, a few with many (Figure 6).
 
-Full scale is 2291 datasets; ``--scale`` trades fidelity for runtime
-(benchmarks/run.py uses 0.25 to stay within CI budgets; the duration figure
-is scale-invariant because bandwidths and totals shrink together only when
---scale-bytes is also given — by default only file counts shrink).
+Full scale is 2291 datasets; ``--scale`` trades fidelity for runtime (the
+duration figure is scale-invariant because bandwidths and totals shrink
+together only when --scale-bytes is also given — by default only file
+counts shrink).
 
 ``--compare-engines`` additionally replays the paper-2022 scenario under the
 fixed-step driver AND the event-driven core (``repro.scenarios.events``) and

@@ -3,8 +3,9 @@
     PYTHONPATH=src python examples/train_with_replication.py [--steps 60]
 
 What it shows, in one run:
-  1. dataset staged from a slow "STORE" site to two pod staging areas via the
-     Figure-4 scheduler over real files (LocalFSTransport + checksums);
+  1. dataset staged from a slow "STORE" site to two pods by the same
+     ``CheckpointReplicator`` that guards the checkpoints: the Figure-4
+     scheduler over real files (LocalFSTransport + checksums);
   2. training on the pod-local copy with periodic checkpoints;
   3. every committed checkpoint replicated cross-site (POD1 + STORE);
   4. a simulated pod loss (primary checkpoint tree destroyed) and recovery
@@ -27,7 +28,6 @@ from repro.checkpoint.ckpt import save_checkpoint
 from repro.checkpoint.replicate import CheckpointReplicator
 from repro.configs import get_config
 from repro.data.sharded import ShardedDataset, write_shards
-from repro.data.staging import StagingArea
 from repro.models.model import LM
 from repro.optim import adamw
 from repro.train.loop import TrainConfig, make_train_step
@@ -41,18 +41,18 @@ def main():
 
     with tempfile.TemporaryDirectory() as td:
         # -- 1. stage the dataset from the slow store to both pods ----------
-        staging = StagingArea(td, store="STORE", pods=("POD0", "POD1"))
-        store_ds = os.path.join(td, "STORE", "datasets", "tokens")
+        staging = CheckpointReplicator(td, primary="STORE",
+                                       replicas=("POD0", "POD1"))
+        store_ds = os.path.join(staging.site_dir("STORE"), "datasets", "tokens")
         rng = np.random.default_rng(0)
         write_shards(store_ds, rng.integers(0, cfg.vocab_size, 200_000
                                             ).astype(np.int32), 4096)
-        staging.register("datasets/tokens")
-        steps = staging.run_until_staged()
-        print(f"[stage] dataset staged to both pods in {steps} scheduler steps; "
-              f"verified={staging.staged_ok('datasets/tokens')}")
+        ok = staging.replicate("datasets/tokens")
+        print(f"[stage] dataset staged to both pods; verified={ok}")
 
         # -- 2. train from the pod-local copy -------------------------------
-        data = ShardedDataset(staging.pod_path("POD0", "datasets/tokens"))
+        data = ShardedDataset(os.path.join(staging.site_dir("POD0"),
+                                           "datasets", "tokens"))
         model = LM(cfg, remat=False)
         params = model.init(jax.random.PRNGKey(0))
         opt = adamw.init(params)

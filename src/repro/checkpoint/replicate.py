@@ -7,6 +7,9 @@ cold store) over ``LocalFSTransport`` with checksum verification.  A pod loss
 then never costs more than the steps since the last commit: restart verifies
 the local manifest, and if the local copy is corrupt or gone, restores from
 the nearest replica (relay order, slow store last — C2 applied to recovery).
+Any directory under the primary replicates the same way: a training dataset
+is staged from a slow store to the pods with a replicator whose primary is
+the store.
 """
 from __future__ import annotations
 

@@ -6,7 +6,7 @@
 from __future__ import annotations
 
 import importlib
-from typing import Dict, List
+from typing import List
 
 from repro.models.config import ModelConfig
 
@@ -32,18 +32,3 @@ def get_config(arch: str) -> ModelConfig:
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
     return mod.CONFIG
 
-
-# ---------------------------------------------------------------- input shapes
-SHAPES: Dict[str, dict] = {
-    "train_4k":    dict(seq_len=4096,   global_batch=256, mode="train"),
-    "prefill_32k": dict(seq_len=32768,  global_batch=32,  mode="prefill"),
-    "decode_32k":  dict(seq_len=32768,  global_batch=128, mode="decode"),
-    "long_500k":   dict(seq_len=524288, global_batch=1,   mode="decode"),
-}
-
-
-def shape_applicable(arch: str, shape: str) -> bool:
-    """long_500k only for sub-quadratic archs (see DESIGN.md §5)."""
-    if shape != "long_500k":
-        return True
-    return get_config(arch).subquadratic

@@ -9,6 +9,5 @@ CONFIG = ModelConfig(
     n_layers=64, d_model=4096, n_heads=0, n_kv_heads=0,
     d_ff=0, vocab_size=65024,
     ssm=SSMConfig(version=1, d_state=16, d_conv=4, expand=2, chunk=256),
-    subquadratic=True,
     max_seq_len=1048576,
 )

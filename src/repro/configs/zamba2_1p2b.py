@@ -15,6 +15,5 @@ CONFIG = ModelConfig(
     ssm=SSMConfig(version=2, d_state=64, d_conv=4, expand=2, headdim=64,
                   n_groups=1, chunk=256),
     hybrid=HybridConfig(shared_attn_every=6),
-    subquadratic=True,
     max_seq_len=1048576,
 )

@@ -18,9 +18,9 @@ Public surface:
 Determinism contract: lane 0 of any ensemble whose first lane carries the
 base spec/seed reproduces the scalar events-engine trajectory bit-for-bit
 (same iteration count, float-exact sim days, identical succeeded-set
-digest).  The numpy backend is the reference; the jax/vmap and Pallas
-backends are validated against it to float tolerance (XLA may contract
-``a*b + c`` to an FMA, so cross-backend bit-identity is not promised).
+digest).  The numpy backend is the reference; the jax/vmap backend is
+validated against it to float tolerance (XLA may contract ``a*b + c`` to an
+FMA, so cross-backend bit-identity is not promised).
 """
 from repro.ensemble.engine import EnsembleResult, run_ensemble
 from repro.ensemble.lanes import LanesEngine, lane_capable

@@ -2,7 +2,7 @@
 draws.
 
 The lanes engine's hot inner operation is ``advance_segment`` over
-``[lane, row]`` float64 arrays.  Three interchangeable implementations:
+``[lane, row]`` float64 arrays.  Two interchangeable implementations:
 
 * ``numpy`` — the bit-exact reference (``repro.core.transport``'s own
   module function; the scalar engine runs the same expressions).
@@ -10,19 +10,16 @@ The lanes engine's hot inner operation is ``advance_segment`` over
   run under a scoped x64 context (``with jax.enable_x64(True)`` — the
   global flag is never touched, so f32 model code elsewhere is unaffected).
   This is the device path: on a TPU, XLA emulates the float64.
-* ``pallas`` — the ``repro.kernels.lane_step`` kernel, interpreted on the
-  CPU backend only; asking for it on an accelerator raises, because
-  compiled Pallas has no float64.
 
-The jax/Pallas backends agree with numpy to float64 round-off but NOT
-necessarily bit-for-bit: XLA may contract ``bytes_done + rate * t`` into an
-FMA.  On a TPU the jax backend's float64 is emulated, and the quantities
-that pass through the division (``adv``, ``moved``, ``t_left``) land about
-1e-10 relative from numpy on a v5e, so trajectories drift further there.
+The jax backend agrees with numpy to float64 round-off but NOT necessarily
+bit-for-bit: XLA may contract ``bytes_done + rate * t`` into an FMA.  On a
+TPU the jax backend's float64 is emulated, and the quantities that pass
+through the division (``adv``, ``moved``, ``t_left``) land about 1e-10
+relative from numpy on a v5e, so trajectories drift further there.
 The determinism contract therefore names numpy the reference backend
-— the lane-0 bit-identity gate always runs it — while the accelerated
-backends are validated by ``tests/test_ensemble.py`` elementwise against
-the reference, and on the chip by ``chip_smoke.py`` lane for lane.
+— the lane-0 bit-identity gate always runs it — while the jax backend is
+validated by ``tests/test_ensemble.py`` elementwise against the reference,
+and on the chip by ``chip_smoke.py`` lane for lane.
 
 ``BatchedFaultInjector`` wraps N independent per-lane ``FaultInjector``
 streams behind one dense-array call.  This is deliberately NOT a vmapped
@@ -91,23 +88,11 @@ def jnp_f64(x):
     return jnp.asarray(x, jnp.float64)
 
 
-def pallas_segment_fn(t, bytes_done, rate, bound):
-    """Pallas kernel backend (CPU interpret mode only; see
-    repro.kernels.lane_step)."""
-    from repro.kernels.lane_step.ops import lane_segment_step
-    t = np.broadcast_to(np.asarray(t, np.float64), bytes_done.shape)
-    return lane_segment_step(t, bytes_done, rate, bound)
-
-
 def make_segment_fn(backend: str):
     if backend == "numpy":
         return numpy_segment_fn
     if backend == "jax":
         return jax_segment_fn
-    if backend == "pallas":
-        from repro.kernels.lane_step.ops import require_cpu_backend
-        require_cpu_backend()
-        return pallas_segment_fn
     raise ValueError(f"unknown segment backend {backend!r}")
 
 

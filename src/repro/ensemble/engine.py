@@ -25,7 +25,7 @@ class EnsembleResult:
     name: str
     n_lanes: int
     engine: str                    # "lanes" | "scalar"
-    backend: str                   # "numpy" | "jax" | "pallas" (lanes only)
+    backend: str                   # "numpy" | "jax" (lanes only)
     lanes: List[LaneResult]
     bands: Dict[str, Dict[str, float]]
 
@@ -50,7 +50,7 @@ class EnsembleResult:
 def _segment_fn(backend: str):
     if backend == "numpy":
         return numpy_segment
-    if backend in ("jax", "pallas"):
+    if backend == "jax":
         from repro.ensemble.batch import make_segment_fn
         return make_segment_fn(backend)
     raise ValueError(f"unknown ensemble backend {backend!r}")

@@ -1,7 +1,7 @@
 """Ensemble CLI.
 
     PYTHONPATH=src python -m repro.ensemble.run --ensemble ensemble-paper-bands \
-        [--lanes N] [--scale S] [--datasets N] [--backend numpy|jax|pallas] \
+        [--lanes N] [--scale S] [--datasets N] [--backend numpy|jax] \
         [--search [--objective sim_days] [--checkpoint FILE] [--chunk K]] \
         [--json out.json] [--verbose]
     PYTHONPATH=src python -m repro.ensemble.run --ensemble <name> --check-lane0
@@ -11,9 +11,9 @@
 replays through the array lanes engine AND through the scalar event engine,
 and the two trajectories — iteration count, float-exact sim days, fault and
 quarantine counters, per-replica bytes, succeeded-set digest — must match
-exactly (the numpy backend is the reference; jax/Pallas backends are
-allowed float64 round-off drift and are gated elementwise in tests, not
-here).  Exit code 4 on any mismatch.
+exactly (the numpy backend is the reference; the jax backend is allowed
+float64 round-off drift and is gated elementwise in tests, not here).  Exit
+code 4 on any mismatch.
 
 ``--search`` runs the checkpointed search driver instead of a plain band
 reduction: lanes evaluate in ``--chunk``-sized pieces, progress persists to
@@ -81,8 +81,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                    help="override the ensemble's lane count")
     p.add_argument("--scale", type=float, default=0.01)
     p.add_argument("--datasets", type=int, default=None)
-    p.add_argument("--backend", default="numpy",
-                   choices=("numpy", "jax", "pallas"))
+    p.add_argument("--backend", default="numpy", choices=("numpy", "jax"))
     p.add_argument("--check-lane0", action="store_true",
                    help="bit-identity gate: diff lane 0 vs the scalar engine")
     p.add_argument("--search", action="store_true",

@@ -245,8 +245,4 @@ def logical_rules(mesh: Mesh, global_batch: int,
         "ssm_ch": "model",
         "ssm_heads": "model",
     }
-    import os
-    if os.environ.get("REPRO_NO_CONSTRAIN") == "1":   # §Perf baseline replay
-        for k in ("ff", "heads", "kv", "ssm_ch", "ssm_heads"):
-            rules[k] = None
     return rules

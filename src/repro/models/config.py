@@ -103,7 +103,6 @@ class ModelConfig:
     # --- numerics / misc ----------------------------------------------------
     norm_eps: float = 1e-5
     max_seq_len: int = 8192
-    subquadratic: bool = False                # eligible for long_500k decode
     notes: str = ""
 
     def __post_init__(self):
@@ -174,7 +173,8 @@ class ModelConfig:
 
 
 def param_count(cfg: ModelConfig) -> Tuple[int, int]:
-    """(total_params, active_params) — analytic, for roofline MODEL_FLOPS."""
+    """(total_params, active_params) — analytic; read by
+    ``launch/shardings.py`` and the tests."""
     d = cfg.d_model
     total = 0
     active = 0

@@ -137,10 +137,9 @@ def causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray,
 # Query-chunk size for the scan-based attention path.  Chosen so the live
 # (B/dp, H, CHUNK_Q, S) f32 logits block stays O(1 GB) per device for the
 # assigned shapes.  This path is what every model runs, on a TPU too: no
-# model calls the Pallas flash kernel.  Env-tunable for chunk-size sweeps.
-import os as _os
-CHUNK_Q = int(_os.environ.get("REPRO_CHUNK_Q", "128"))
-_CHUNK_THRESHOLD = 1 << int(_os.environ.get("REPRO_CHUNK_THRESHOLD_LOG2", "22"))
+# model calls the Pallas flash kernel.
+CHUNK_Q = 128
+_CHUNK_THRESHOLD = 1 << 22
 
 
 def _sdpa_block(q, k, v, q_pos, k_pos, window, valid, scale) -> jnp.ndarray:

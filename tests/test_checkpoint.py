@@ -86,6 +86,29 @@ def test_replicator_restores_from_replica_when_primary_lost(tmp_path):
     np.testing.assert_array_equal(np.asarray(tree["w"]), np.asarray(t["w"]))
 
 
+def test_replicator_stages_a_dataset_from_the_store(tmp_path):
+    """A dataset directory replicates like a checkpoint: primary STORE, the
+    pods as replicas; each copy verifies and feeds the same batches."""
+    from repro.data.sharded import ShardedDataset, write_shards
+    rep = CheckpointReplicator(str(tmp_path), primary="STORE",
+                               replicas=("POD0", "POD1"))
+    src = os.path.join(rep.site_dir("STORE"), "datasets", "tokens")
+    tokens = np.random.default_rng(0).integers(0, 1000, 40_000
+                                               ).astype(np.int32)
+    assert write_shards(src, tokens, 4096) == 9
+    assert rep.replicate("datasets/tokens")
+    manifest = Manifest.scan(src)
+    assert len(manifest.entries) == 10
+    for pod in ("POD0", "POD1"):
+        copy = os.path.join(rep.site_dir(pod), "datasets", "tokens")
+        assert manifest.verify(copy) == {}
+    want, _ = next(ShardedDataset(src).batches(2, 64))
+    got, _ = next(ShardedDataset(os.path.join(
+        rep.site_dir("POD0"), "datasets", "tokens")).batches(2, 64))
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k])
+
+
 def _over_one_write_slice():
     """float32 rows of 7: more bytes than one write slice, not a multiple."""
     rows = 2 * ckpt._WRITE_BYTES // 28 + 1

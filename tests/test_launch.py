@@ -1,4 +1,4 @@
-"""Launcher CLIs, sharding rules, and the HLO collective parser."""
+"""Launcher CLIs and sharding rules."""
 import jax
 import numpy as np
 import pytest
@@ -6,8 +6,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.launch import shardings as SH
-from repro.launch.analytic import analytic_cost
-from repro.models.config import param_count
 from repro.models.model import LM
 
 
@@ -42,48 +40,6 @@ def test_logical_rules_divisibility_gate():
     rules_odd = SH.logical_rules(FakeMesh(), 256, cfg_odd)
     assert rules_div["heads"] == "model"
     assert rules_odd["heads"] is None         # 8 kv heads % 16 != 0
-
-
-def test_analytic_cost_sane():
-    """Analytic FLOPs must dominate MODEL_FLOPS (waste >= 0) and train must
-    cost more than prefill per token."""
-    cfg = get_config("qwen3-14b")
-    train = analytic_cost(cfg, 256, 4096, "train")
-    prefill = analytic_cost(cfg, 32, 32768, "prefill")
-    decode = analytic_cost(cfg, 128, 32768, "decode")
-    assert train["flops"] > train["model_flops"]
-    assert prefill["flops"] > prefill["model_flops"] * 0.5
-    # decode reads the whole cache per step
-    assert decode["bytes"] > 0 and decode["flops"] > 0
-    tot, act = param_count(cfg)
-    assert tot == act  # dense
-
-
-def test_collective_parser_trip_counts():
-    """Synthetic HLO (XLA-style op naming): an all-reduce inside a while body
-    whose xs have leading dim 6 must be counted 6x; nested whiles multiply
-    (trip counts recovered from each body's dynamic-slice over its xs)."""
-    from repro.launch.dryrun import parse_collectives
-    hlo = """
-%inner_body (p: (s32[], f32[4,2])) -> (s32[], f32[4,2]) {
-  %gte.0 = f32[4,2] get-tuple-element(%p), index=1
-  %ds.0 = f32[1,2] dynamic-slice(%gte.0, %i, %z), dynamic_slice_sizes={1,2}
-  %all-reduce.0 = f32[2,2] all-reduce(%x), channel_id=1, replica_groups=[4,4]<=[16], to_apply=%add
-}
-%outer_body (q: (s32[], f32[6,8])) -> (s32[], f32[6,8]) {
-  %gte.1 = f32[6,8] get-tuple-element(%q), index=1
-  %ds.1 = f32[1,8] dynamic-slice(%gte.1, %j, %z2), dynamic_slice_sizes={1,8}
-  %w.0 = (s32[], f32[4,2]) while(%t0), condition=%c1, body=%inner_body
-  %all-reduce.1 = f32[8] all-reduce(%y), channel_id=2, replica_groups=[4,4]<=[16], to_apply=%add
-}
-ENTRY %main (a: f32[6,8]) -> f32[8] {
-  %w.1 = (s32[], f32[6,8]) while(%t1), condition=%c2, body=%outer_body
-  %all-reduce.2 = f32[16] all-reduce(%a2), channel_id=3, replica_groups=[4,4]<=[16], to_apply=%add
-}
-"""
-    out = parse_collectives(hlo, scan_lengths=(6, 4))
-    # entry: 1; outer (x6): 6; inner (x6x4): 24 -> total 31 all-reduces
-    assert out["counts"]["all-reduce"] == 31, out["counts"]
 
 
 def test_cache_specs_shard_batch_and_heads():

@@ -103,7 +103,7 @@ def test_prefill_decode_consistency(arch, rng_key):
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_param_count_matches_instantiated(arch, rng_key):
-    """Analytic param_count (used for roofline MODEL_FLOPS) must match the
+    """Analytic param_count (read by the sharding rules) must match the
     actually instantiated smoke model within 2%; an expert model's too when
     it holds a share of its routed experts."""
     cfg = get_config(arch).smoke()
@@ -117,6 +117,30 @@ def test_param_count_matches_instantiated(arch, rng_key):
                      for x in jax.tree_util.tree_leaves(params))
         predicted, _ = param_count(c)
         assert abs(actual - predicted) / actual < 0.02, (arch, actual, predicted)
+
+
+@pytest.mark.parametrize("window", [None, 256])
+def test_sdpa_query_chunks_equal_one_block(window, rng_key):
+    """Over ``_CHUNK_THRESHOLD`` scores, ``sdpa`` scans remat'd query chunks
+    over all keys; each row's result equals the one-block computation."""
+    from repro.models import layers
+    B, T, H, Hkv, hd = 1, 2304, 2, 1, 16
+    assert T * T > layers._CHUNK_THRESHOLD and T % layers.CHUNK_Q == 0
+    kq, kk, kv = jax.random.split(rng_key, 3)
+    q = jax.random.normal(kq, (B, T, H, hd), jnp.float32)
+    k = jax.random.normal(kk, (B, T, Hkv, hd), jnp.float32)
+    v = jax.random.normal(kv, (B, T, Hkv, hd), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+
+    def chunked(q, k, v):
+        return layers.sdpa(q, k, v, pos, pos, window=window)
+
+    assert "scan" in str(jax.make_jaxpr(chunked)(q, k, v))
+    got = jax.jit(chunked)(q, k, v)
+    want = jax.jit(lambda q, k, v: layers._sdpa_block(
+        q, k, v, pos, pos, window, None, hd ** -0.5))(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=1e-6)
 
 
 def test_sliding_window_masks_history(rng_key):

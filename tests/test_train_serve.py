@@ -10,7 +10,8 @@ from repro.checkpoint.replicate import CheckpointReplicator
 from repro.configs import get_config
 from repro.models.model import LM
 from repro.serve.engine import Engine
-from repro.train.loop import TrainConfig, train
+from repro.optim import adamw
+from repro.train.loop import TrainConfig, make_train_step, train
 
 
 def test_train_loss_decreases(tmp_path):
@@ -46,6 +47,29 @@ def test_train_with_replication_protects_against_pod_loss(tmp_path):
     train(cfg, tc)
     pod1 = os.path.join(rep.site_dir("POD1"), "ckpts")
     assert sorted(os.listdir(pod1)) == ["step-000005", "step-000010"]
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "qwen3-14b"])
+def test_microbatch_accumulation_matches_one_batch(arch):
+    """Two accumulated microbatches give the whole batch's loss and, after
+    one step, the same parameters."""
+    cfg = get_config(arch).smoke()
+    model = LM(cfg, remat=False)
+    rng = np.random.default_rng(0)
+    toks = rng.integers(0, cfg.vocab_size, (4, 33)).astype(np.int32)
+    batch = {"tokens": jnp.asarray(toks[:, :-1]),
+             "labels": jnp.asarray(toks[:, 1:])}
+    out = {}
+    for mb in (1, 2):
+        params = model.init(jax.random.PRNGKey(0))
+        tc = TrainConfig(steps=10, batch_size=4, seq_len=32, microbatches=mb)
+        step = make_train_step(model, adamw.AdamWConfig(), tc)
+        params, _, loss, _ = step(params, adamw.init(params), batch)
+        out[mb] = (float(loss), jax.device_get(params))
+    assert abs(out[2][0] - out[1][0]) <= 1e-6 * abs(out[1][0]), out
+    for a, b in zip(jax.tree_util.tree_leaves(out[1][1]),
+                    jax.tree_util.tree_leaves(out[2][1])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_engine_matches_manual_decode():
