@@ -199,6 +199,26 @@ def test_saved_files_equal_np_save_and_replicas_restore_bit_equal(
         assert np.asarray(back).tobytes() == np.asarray(leaf).tobytes(), name
 
 
+def test_manifest_digests_equal_the_oracle(tmp_path):
+    """A saved checkpoint's ``MANIFEST.json`` holds, for every file, its size
+    and ``checksum_bytes_np`` of its bytes, files of several fold blocks
+    included: manifests written by any version of the hash are
+    interchangeable."""
+    from repro.core.integrity import _FOLD_WORDS
+    from repro.kernels.checksum.ref import checksum_bytes_np
+    t = every_leaf_kind()
+    t["blocks"] = np.arange(2 * (3 * _FOLD_WORDS + 1),
+                            dtype=np.uint32).reshape(2, -1)
+    d = save_checkpoint(str(tmp_path), 7, t)
+    entries = Manifest.load(os.path.join(d, "MANIFEST.json")).entries
+    assert max(size for size, _ in entries.values()) > 12 * _FOLD_WORDS
+    assert set(entries) == set(os.listdir(d)) - {"MANIFEST.json",
+                                                 "COMMITTED"}
+    for rel, (size, csum) in entries.items():
+        data = (tmp_path / os.path.basename(d) / rel).read_bytes()
+        assert (size, csum) == (len(data), checksum_bytes_np(data)), rel
+
+
 def test_elastic_reshard_plan():
     from repro.checkpoint.elastic import plan_reshard
     from jax.sharding import PartitionSpec as P

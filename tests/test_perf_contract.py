@@ -8,6 +8,8 @@ guarantees the optimizations must preserve (vectorized == scalar mover,
 cache == database, streamed == whole-buffer checksums).
 """
 import dataclasses
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -188,6 +190,58 @@ def test_streaming_checksum_matches_whole_buffer():
         s.update(data[off:])
         assert s.digest() == want
     assert StreamingChecksum().digest() == file_checksum(b"")
+
+
+def test_streaming_checksum_threads_keep_their_own_scratch():
+    """Eight threads hashing eight different multi-block buffers at once,
+    released together and switching often, each get their own oracle
+    digest: the per-thread fold scratch is never shared."""
+    from repro.core.integrity import _FOLD_WORDS
+    rng = np.random.default_rng(8)
+    bufs = [rng.bytes(8 * _FOLD_WORDS + 4099 * i + i % 4) for i in range(8)]
+    want = [file_checksum(b) for b in bufs]
+    got = [None] * 8
+    start = threading.Barrier(8)
+
+    def work(i):
+        s = StreamingChecksum()
+        start.wait(timeout=60)
+        view = memoryview(bufs[i])
+        for off in range(0, len(view), 1_000_003):
+            s.update(view[off:off + 1_000_003])
+        got[i] = s.digest()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i,)) for i in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert got == want
+
+
+@pytest.mark.parametrize("size", ["0", "1", "3", "scan-1", "scan+1",
+                                  "3 blocks+2"])
+def test_stream_file_checksum_matches_oracle(tmp_path, size):
+    """``stream_file_checksum`` on files around its read buffer and the fold
+    block equals the oracle, after the thread's buffer has held another
+    file's bytes."""
+    from repro.core.integrity import (_FOLD_WORDS, _SCAN_CHUNK,
+                                      stream_file_checksum)
+    n = {"0": 0, "1": 1, "3": 3, "scan-1": _SCAN_CHUNK - 1,
+         "scan+1": _SCAN_CHUNK + 1, "3 blocks+2": 12 * _FOLD_WORDS + 2}[size]
+    rng = np.random.default_rng(n)
+    other, path = tmp_path / "other", tmp_path / "data"
+    other.write_bytes(rng.bytes(_SCAN_CHUNK))
+    data = rng.bytes(n)
+    path.write_bytes(data)
+    assert stream_file_checksum(str(other))[0] == _SCAN_CHUNK
+    assert stream_file_checksum(str(path)) == (n, file_checksum(data))
 
 
 def test_streaming_checksum_order_sensitive():

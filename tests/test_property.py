@@ -1,4 +1,6 @@
 """Hypothesis property tests on system invariants."""
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -204,6 +206,105 @@ def test_streaming_checksum_any_chunking(data, small_chunks, seed):
         i += step
     s.update(b"")
     assert s.digest() == checksum_bytes_np(data)
+
+
+@contextlib.contextmanager
+def _fold_block(words):
+    """Run ``core.integrity``'s in-place fold at a block of ``words`` words,
+    so that small buffers span several blocks."""
+    from repro.core import integrity
+    saved = integrity._FOLD_WORDS, integrity._BASE
+    integrity._FOLD_WORDS = words
+    integrity._BASE = np.arange(words, dtype=np.uint32)
+    try:
+        yield
+    finally:
+        integrity._FOLD_WORDS, integrity._BASE = saved
+
+
+def _as_kind(kind, data, lo, hi):
+    """``data[lo:hi]`` as a ``bytes``, a ``bytearray`` or a ``memoryview``
+    into the whole buffer (so at any byte offset)."""
+    if kind == "memoryview":
+        return memoryview(data)[lo:hi]
+    return (bytes if kind == "bytes" else bytearray)(data[lo:hi])
+
+
+@given(st.sampled_from([1, 2, 7, 16]), st.integers(2, 5), st.integers(0, 3),
+       st.lists(st.integers(1, 3), min_size=0, max_size=8),
+       st.integers(0, 2 ** 31 - 1))
+@settings(max_examples=60, deadline=None)
+def test_streaming_fold_blocks_any_split(block, nblocks, tail, tiny, seed):
+    """Several fold blocks plus 0-3 tail bytes, fed in random splits: 1-3
+    byte updates first, a cut within 3 bytes of every block boundary, random
+    cuts, each piece as bytes, bytearray or memoryview; the digest is the
+    oracle's."""
+    from repro.core.integrity import StreamingChecksum
+    rng = np.random.default_rng(seed)
+    data = rng.integers(0, 256, 4 * block * nblocks + tail,
+                        dtype=np.uint8).tobytes()
+    cuts = set(np.cumsum(tiny).tolist())
+    cuts |= {4 * block * b + int(rng.integers(-3, 4))
+             for b in range(1, nblocks + 1)}
+    cuts |= set(rng.integers(0, len(data) + 1, size=4).tolist())
+    cuts = sorted(c for c in cuts if 0 < c < len(data))
+    kinds = ("bytes", "bytearray", "memoryview")
+    s = StreamingChecksum()
+    with _fold_block(block):
+        for i, (lo, hi) in enumerate(zip([0] + cuts, cuts + [len(data)])):
+            s.update(_as_kind(kinds[int(rng.integers(3))], data, lo, hi))
+        got = s.digest()
+    assert got == checksum_bytes_np(data)
+
+
+@pytest.mark.parametrize("kind", ["bytes", "bytearray", "memoryview"])
+def test_streaming_fold_real_block_each_input_type(kind):
+    """At the module's own block: two blocks and three bytes, cut one word
+    before the block boundary and three bytes after it."""
+    from repro.core.integrity import _FOLD_WORDS, StreamingChecksum
+    data = np.random.default_rng(18).bytes(8 * _FOLD_WORDS + 3)
+    cuts = [0, 4 * _FOLD_WORDS - 4, 4 * _FOLD_WORDS + 3, len(data)]
+    s = StreamingChecksum()
+    for lo, hi in zip(cuts, cuts[1:]):
+        s.update(_as_kind(kind, data, lo, hi))
+    assert s.digest() == checksum_bytes_np(data)
+
+
+@pytest.mark.parametrize("start_word", [2 ** 32 - 40, 2 ** 32 - 1, 2 ** 32,
+                                        2 ** 32 + 1, 2 ** 33 + 7])
+@pytest.mark.parametrize("nwords", [0, 1, 16, 53])
+def test_block_fold_matches_oracle_across_2_32(start_word, nwords):
+    """The block fold itself, with positions that wrap past 2**32 inside a
+    block, between blocks, or start above it, equals ``fold_words_np``."""
+    from repro.core.integrity import _fold_words
+    from repro.kernels.checksum.ref import fold_words_np
+    words = np.random.default_rng(nwords).integers(
+        0, 2 ** 32, nwords, dtype=np.uint32)
+    with _fold_block(16):
+        got = _fold_words(words, start_word)
+    assert got == fold_words_np(words, start_word)
+
+
+@pytest.mark.parametrize("word", ["first", "last_of_block", "first_of_block",
+                                  "last"])
+@pytest.mark.parametrize("bit", [0, 13, 31])
+def test_streaming_fold_detects_one_flipped_bit(word, bit):
+    """One flipped bit in the first word, either side of a block boundary
+    or in the last word changes the digest, which stays the oracle's."""
+    from repro.core.integrity import StreamingChecksum
+    block = 16
+    data = bytearray(np.random.default_rng(bit).bytes(4 * block * 3 + 2))
+    index = {"first": 0, "last_of_block": block - 1, "first_of_block": block,
+             "last": len(data) // 4}[word]
+    flipped = bytearray(data)
+    # the last word is the 2-byte tail: flip within the bytes it has
+    flipped[min(4 * index + bit // 8, len(data) - 1)] ^= 1 << (bit % 8)
+    with _fold_block(block):
+        h0 = StreamingChecksum().update(data).digest()
+        h1 = StreamingChecksum().update(flipped).digest()
+    assert h0 == checksum_bytes_np(bytes(data))
+    assert h1 == checksum_bytes_np(bytes(flipped))
+    assert h0 != h1
 
 
 # ---------------------------------------------- batched fair-share pricer
